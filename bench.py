@@ -137,8 +137,8 @@ def bench_e2e(pid, pk, value, n_runs=3, segment_sort="auto"):
         return elapsed, stages
 
     run(100)  # warmup/compile
-    # min-of-n: the host->device link bandwidth varies ~2x between runs;
-    # the minimum is the honest sustained capability of the path.
+    # min-of-n: the fastest run is the sustained capability of the path;
+    # the spread between runs on a PCIe-attached chip is not measured yet.
     results = [run(i) for i in range(n_runs)]
     best_s, best_stages = min(results, key=lambda r: r[0])
     phases = _coarse_phases(best_stages, best_s)
@@ -986,6 +986,8 @@ def _resilience_counters():
 
 
 def main():
+    from pipelinedp_tpu import compile_cache
+    compile_cache.configure(os.path.dirname(os.path.abspath(__file__)))
     cpu_pps = bench_cpu_baseline()
     steady = {}
     try:

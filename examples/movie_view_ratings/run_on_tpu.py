@@ -18,15 +18,18 @@ import numpy as np
 import os
 import sys
 
-sys.path.insert(
-    0, os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "..")))
+_CHECKOUT = os.path.abspath(
+    os.path.join(os.path.dirname(__file__), "..", ".."))
+sys.path.insert(0, _CHECKOUT)
 
 import pipelinedp_tpu as pdp
+from pipelinedp_tpu import compile_cache
 
 from common_utils import parse_file, synthesize_columns, write_to_file
 
 
 def main():
+    compile_cache.configure(_CHECKOUT)
     parser = argparse.ArgumentParser()
     parser.add_argument("--input_file", default=None,
                         help="Netflix-prize format input; synthetic if unset")

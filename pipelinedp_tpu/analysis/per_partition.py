@@ -552,10 +552,10 @@ def compute_per_partition_arrays(pre: PreAggregates,
     """Runs every error model over the whole configuration grid.
 
     use_device: True forces the jitted device sweep
-    (analysis/device_sweep.py) — any device failure propagates; False
-    forces host numpy; None auto-selects (device when an accelerator is
-    present and the grid is large), falling back to host with a warning if
-    the device path fails.
+    (analysis/device_sweep.py); False forces host numpy; None auto-selects
+    (device when an accelerator is present and the grid is large). A
+    device sweep that fails raises either way: it never reruns quietly on
+    the host.
     mesh: a jax.sharding.Mesh to shard the sweep over (implies device).
     """
     if n_partitions is None:
@@ -564,31 +564,17 @@ def compute_per_partition_arrays(pre: PreAggregates,
     from pipelinedp_tpu.analysis import device_sweep
     if mesh is not None:
         use_device = True
-    forced_device = use_device is True
     if use_device is None:
         use_device = device_sweep.should_use_device(pre.num_groups,
                                                     len(configs))
     n_units = np.bincount(pre.pk_ids, minlength=n_partitions)
-    metric_errors = None
     approx_moments = None
     device_state = None
     if use_device:
-        try:
-            device_state, metric_errors, approx_moments = (
-                _build_device_sweep(pre, configs, ordered_metrics,
-                                    n_partitions, public_partitions,
-                                    n_units, mesh=mesh))
-        except device_sweep.SWEEP_ERRORS:
-            if forced_device:
-                raise
-            device_sweep.logger.warning(
-                "Device utility-analysis sweep failed; falling back to the "
-                "host path.",
-                exc_info=True)
-            metric_errors = None
-            approx_moments = None
-            device_state = None
-    if metric_errors is None:
+        device_state, metric_errors, approx_moments = _build_device_sweep(
+            pre, configs, ordered_metrics, n_partitions, public_partitions,
+            n_units, mesh=mesh)
+    else:
         metric_errors = [
             compute_metric_errors(pre, configs, m, n_partitions)
             for m in ordered_metrics

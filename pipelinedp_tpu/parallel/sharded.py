@@ -40,18 +40,6 @@ from pipelinedp_tpu.ops import quantiles as quantile_ops
 from pipelinedp_tpu.runtime import driver as driver_lib
 
 
-def shard_map(fn, *, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map with a fallback for older JAX releases, where it
-    lives in jax.experimental.shard_map and the replication-check flag is
-    named check_rep."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
 def _spec(mesh: Mesh) -> P:
     """Row arrays shard over every mesh axis (dcn included)."""
     return P(tuple(mesh.axis_names))
@@ -212,7 +200,7 @@ def _scalar_kernel(mesh: Mesh, padded_p: int, has_l1: bool = False,
 
     spec = _spec(mesh)
     part = _part_spec(mesh)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(P(),) + (spec,) * 4 + (P(),) * (8 if has_l1 else 7),
@@ -254,7 +242,7 @@ def _vector_kernel(mesh: Mesh, padded_p: int, norm_ord: int,
 
     spec = _spec(mesh)
     part = _part_spec(mesh)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(P(),) + (spec,) * 4 + (P(),) * (4 if has_l1 else 3),
@@ -285,7 +273,7 @@ def _quantile_kernel(mesh: Mesh, padded_p: int, num_leaves: int,
         return _reduce_scatter(hist, scatter)
 
     spec = _spec(mesh)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(P(),) + (spec,) * 4 + (P(),) * (5 if has_l1 else 4),
@@ -377,7 +365,7 @@ def _row_mask_kernel(mesh: Mesh, has_l1: bool = False):
                                        l1_cap=l1_args[0] if has_l1 else None)
 
     spec = _spec(mesh)
-    fn = shard_map(local_step,
+    fn = jax.shard_map(local_step,
                        mesh=mesh,
                        in_specs=(P(),) + (spec,) * 3 + (P(),) *
                        (3 if has_l1 else 2),
@@ -397,7 +385,7 @@ def _local_pk_sort_kernel(mesh: Mesh):
         return pk[order], value[order], mask[order]
 
     spec = _spec(mesh)
-    fn = shard_map(local_step,
+    fn = jax.shard_map(local_step,
                        mesh=mesh,
                        in_specs=(spec,) * 3,
                        out_specs=(spec,) * 3,
@@ -423,7 +411,7 @@ def _block_rows_cap_kernel(mesh: Mesh, block_p: int, n_blocks: int):
         return m
 
     spec = _spec(mesh)
-    fn = shard_map(local_step,
+    fn = jax.shard_map(local_step,
                        mesh=mesh,
                        in_specs=(spec, spec),
                        out_specs=P(),
@@ -458,7 +446,7 @@ def _block_hist_kernel(mesh: Mesh, block_p: int, num_leaves: int,
         return _reduce_scatter(hist, scatter)
 
     spec = _spec(mesh)
-    fn = shard_map(local_step,
+    fn = jax.shard_map(local_step,
                        mesh=mesh,
                        in_specs=(spec,) * 3 + (P(),) * 3,
                        out_specs=_part_spec(mesh),
@@ -618,7 +606,7 @@ def _codec_scalar_kernel(mesh: Mesh, padded_p: int, fmt, has_l1: bool,
             *(_reduce_scatter(a, scatter_axes) for a in accs))
 
     spec = _spec(mesh)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(P(), spec, spec, spec) + (P(),) * (8 if has_l1 else 7),
@@ -673,7 +661,7 @@ def _codec_compact_kernel(mesh: Mesh, padded_p: int, fmt, max_groups: int,
             cg.norm_sq_sum, jnp.reshape(cg.n_kept, (1,)))
 
     spec = _spec(mesh)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(P(), spec, spec, spec) + (P(),) * (8 if has_l1 else 7),
@@ -712,7 +700,7 @@ def _compact_merge_kernel(mesh: Mesh, padded_p: int, n_c: int, need_flags):
 
     spec = _spec(mesh)
     part = _part_spec(mesh)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(columnar.PartitionAccumulators(*(part,) * 5),)
@@ -1019,7 +1007,7 @@ def _codec_batch_kernel(mesh: Mesh, padded_p: int, fmt, has_l1: bool,
 
     spec = _spec(mesh)
     lane_part = P(None, _scatter_axes(mesh))
-    fn = shard_map(
+    fn = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(P(), spec, spec, spec) + (P(),) * (8 if has_l1 else 7),
