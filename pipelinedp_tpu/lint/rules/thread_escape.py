@@ -1,11 +1,11 @@
 """DPL008 — thread-escape: unlocked shared-state writes in pool workers.
 
 The prefetch/encode pools (ops/streaming.py, parallel/sharded.py) and the
-profiler's sink machinery share objects between the pipeline thread and
-worker threads. The audited handoffs are (a) the profiler lock
-(``_add_stage_time`` under ``_sink_lock``) and (b) the adopt/merge
-protocol (``profiler.adopt_sinks(parent_sinks)`` installing a parent's
-collectors before any recording). A worker callable that *writes* an
+span sinks (obs/trace.py, re-exported by the profiler) share objects
+between the pipeline thread and worker threads. The audited handoffs are
+(a) the sink lock (``_add_stage_time`` under ``_sink_lock``) and (b) the
+adopt/merge protocol (``adopt_sinks(parent_sinks)`` installing a
+parent's collectors before any recording). A worker callable that *writes* an
 attribute or container element of a captured object the enclosing scope
 also touches — outside any lock and outside the adopt handoff — is a data
 race: torn stage timings at best, a corrupted slab index feeding the DP

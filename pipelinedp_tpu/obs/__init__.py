@@ -4,13 +4,15 @@ The serving fleet's answer to "why was this query slow", "what is p99
 admission wait", and "exactly which DP releases has tenant X been
 charged for" (OBSERVABILITY.md):
 
-  * :mod:`~pipelinedp_tpu.obs.trace` — a thread-safe,
-    zero-cost-when-disabled span :class:`~pipelinedp_tpu.obs.trace
-    .Tracer` with explicit parent links, threaded through
-    ``DatasetSession.query``/``query_batch``, the ``SessionManager``
-    gate, the ``runtime.SlabDriver`` windows (encode / transfer /
-    dispatch / sync, retry / degrade / watchdog events) and the fused
-    finalize epilogue; exports Chrome trace-event JSON
+  * :mod:`~pipelinedp_tpu.obs.trace` — the program's one span call,
+    ``trace.span(name, **attrs)``: a profiler annotation on the device
+    trace's clock, the stage-time sinks, and (when a
+    :class:`~pipelinedp_tpu.obs.trace.Tracer` is installed) a
+    parent-linked span, threaded through ``DatasetSession.query`` /
+    ``query_batch``, the ``SessionManager`` gate, the engine's encode,
+    wire preparation and finalize, and the ``runtime.SlabDriver``
+    windows (wait / transfer / dispatch / sync, retry / degrade /
+    watchdog events); exports Chrome trace-event JSON
     (Perfetto-loadable) per query or per process.
     Knob: ``PIPELINEDP_TPU_TRACE``.
   * :mod:`~pipelinedp_tpu.obs.metrics` — a typed registry (counters,

@@ -459,6 +459,22 @@ def release_tick_seconds() -> Histogram:
         "Scheduled continual-release window latency by outcome.")
 
 
+# Compile (or persistent-cache load) seconds of one program: from a cache
+# hit of a small epilogue to a cold mesh chunk program (~630 s).
+COMPILE_BUCKETS_S: Tuple[float, ...] = (
+    0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 10.0, 30.0, 60.0, 120.0, 300.0, 600.0)
+
+
+def compile_seconds() -> Histogram:
+    """One XLA compile or persistent-cache load, labeled by program (the
+    jit function's name); fed by the profiler's jax.monitoring
+    listener, which also counts the ``jit/compiles`` event."""
+    return default_registry().histogram(
+        "pipelinedp_tpu_compile_seconds",
+        "XLA compile or compile-cache load latency by program.",
+        buckets=COMPILE_BUCKETS_S)
+
+
 def fleet_resident_bytes() -> Gauge:
     """Fleet-wide resident bytes across admitted sessions."""
     return default_registry().gauge(

@@ -455,20 +455,21 @@ def _sampled_from_packed(skeys, n: int, n_valid, segbits: int, pkbits: int,
     """Shared epilogue over packed-key-sorted rows: field extraction,
     segment/group boundaries, Linf/L0 sampling. Validity is positional
     (padding keys are all-ones, strictly above any valid key)."""
-    svalid = jnp.arange(n, dtype=jnp.int32) < n_valid
-    sseg = _extract_key_bits(skeys, 0, segbits).astype(jnp.int32)
-    spk = _extract_key_bits(skeys, segbits + 32, pkbits).astype(jnp.int32)
+    with jax.named_scope("sample"):
+        svalid = jnp.arange(n, dtype=jnp.int32) < n_valid
+        sseg = _extract_key_bits(skeys, 0, segbits).astype(jnp.int32)
+        spk = _extract_key_bits(skeys, segbits + 32, pkbits).astype(jnp.int32)
 
-    is_start = _prefix_changed(skeys, segbits + 32 + pkbits)
-    keep_row = svalid & (_segment_rank(is_start) < linf_cap)
-    group_id = (jnp.cumsum(is_start) - 1).astype(jnp.int32)
-    is_pid_start = _prefix_changed(skeys, segbits)
-    first_group_of_pid = jax.lax.cummax(
-        jnp.where(is_pid_start, group_id, 0))
-    group_rank = group_id - first_group_of_pid
-    keep_group_row = svalid & (group_rank < l0_cap)
-    return SampledRows(order, sseg, spk, svalid, is_start, group_id,
-                       keep_row, keep_group_row, sval)
+        is_start = _prefix_changed(skeys, segbits + 32 + pkbits)
+        keep_row = svalid & (_segment_rank(is_start) < linf_cap)
+        group_id = (jnp.cumsum(is_start) - 1).astype(jnp.int32)
+        is_pid_start = _prefix_changed(skeys, segbits)
+        first_group_of_pid = jax.lax.cummax(
+            jnp.where(is_pid_start, group_id, 0))
+        group_rank = group_id - first_group_of_pid
+        keep_group_row = svalid & (group_rank < l0_cap)
+        return SampledRows(order, sseg, spk, svalid, is_start, group_id,
+                           keep_row, keep_group_row, sval)
 
 
 def _sample_rows_and_groups_presorted(key: jax.Array, pid: jnp.ndarray,
@@ -1116,23 +1117,28 @@ def bound_and_aggregate(key: jax.Array,
         # at least float32 regardless of the value dtype.
         zeros = jnp.zeros((num_partitions,), dtype=jnp.float32)
         return PartitionAccumulators(zeros, zeros, zeros, zeros, zeros)
-    s = _dispatch_sampler(
-        key, pid, pk, valid, linf_cap, l0_cap, l1_cap,
-        num_partitions=num_partitions, max_segments=max_segments,
-        pid_sorted=pid_sorted, tile_rows=tile_rows, tile_slack=tile_slack,
-        hash_bins=hash_bins, hash_bin_rows=hash_bin_rows,
-        value=_narrow_sort_value(value, value_is_index, value_sort_bits))
+    with jax.named_scope("group_sort"):
+        s = _dispatch_sampler(
+            key, pid, pk, valid, linf_cap, l0_cap, l1_cap,
+            num_partitions=num_partitions, max_segments=max_segments,
+            pid_sorted=pid_sorted, tile_rows=tile_rows,
+            tile_slack=tile_slack, hash_bins=hash_bins,
+            hash_bin_rows=hash_bin_rows,
+            value=_narrow_sort_value(value, value_is_index,
+                                     value_sort_bits))
     if isinstance(s, BinnedRows):
         # Sortless group stage: per-group sums inside the bins, one
         # stacked scatter straight to the partition accumulators.
-        return _hash_partition_accumulators(
-            s, pk, num_partitions=num_partitions, row_clip_lo=row_clip_lo,
-            row_clip_hi=row_clip_hi, middle=middle,
-            group_clip_lo=group_clip_lo, group_clip_hi=group_clip_hi,
-            need_count=need_count, need_sum=need_sum, need_norm=need_norm,
-            need_norm_sq=need_norm_sq, has_group_clip=has_group_clip,
-            value_is_index=value_is_index, value_lo=value_lo,
-            value_scale=value_scale)
+        with jax.named_scope("group_reduce"):
+            return _hash_partition_accumulators(
+                s, pk, num_partitions=num_partitions,
+                row_clip_lo=row_clip_lo, row_clip_hi=row_clip_hi,
+                middle=middle, group_clip_lo=group_clip_lo,
+                group_clip_hi=group_clip_hi, need_count=need_count,
+                need_sum=need_sum, need_norm=need_norm,
+                need_norm_sq=need_norm_sq, has_group_clip=has_group_clip,
+                value_is_index=value_is_index, value_lo=value_lo,
+                value_scale=value_scale)
     sval, sval_i = _widen_sorted_value(s.sval, value_is_index, value_lo,
                                        value_scale)
 
@@ -1165,14 +1171,15 @@ def bound_and_aggregate(key: jax.Array,
         prow = functools.partial(jax.ops.segment_sum,
                                  segment_ids=spk_safe,
                                  num_segments=num_partitions)
-        return PartitionAccumulators(
-            pid_count=prow(start_w * kg),
-            count=prow(wk) if need_count else zeros,
-            sum=prow(vclip * wk) if need_sum else zeros,
-            norm_sum=prow(vnorm * wk) if need_norm else zeros,
-            norm_sq_sum=prow(vnorm * vnorm * wk)
-            if need_norm_sq else zeros,
-        )
+        with jax.named_scope("scatter"):
+            return PartitionAccumulators(
+                pid_count=prow(start_w * kg),
+                count=prow(wk) if need_count else zeros,
+                sum=prow(vclip * wk) if need_sum else zeros,
+                norm_sum=prow(vnorm * wk) if need_norm else zeros,
+                norm_sq_sum=prow(vnorm * vnorm * wk)
+                if need_norm_sq else zeros,
+            )
     keepg_start = (s.is_start & s.svalid & s.keep_group_row).astype(dtype)
     gseg = functools.partial(jax.ops.segment_sum,
                              segment_ids=s.group_id,
@@ -1180,41 +1187,45 @@ def bound_and_aggregate(key: jax.Array,
                              indices_are_sorted=True)
     # Each gated-off accumulator saves one full-HBM group pass and one
     # partition pass (the kernel is pass-count bound; module docstring).
-    if int_accumulate and sval_i is not None:
-        # Narrow-dtype group accumulation (gate: int_accumulation_plan).
-        # Counts and clipped sums are exact integers in both domains, so
-        # the int32 sums widen to the legacy float32 bits at the fold.
-        w_i = s.keep_row.astype(jnp.int32)
-        vclip_i = jnp.clip(
-            jnp.asarray(value_lo).astype(jnp.int32)
-            + sval_i * jnp.asarray(value_scale).astype(jnp.int32),
-            int_clip_lo, int_clip_hi)
-        g_count = gseg(w_i).astype(dtype) if need_count else None
-        g_sum = (jnp.clip(gseg(vclip_i * w_i).astype(dtype),
-                          group_clip_lo, group_clip_hi)
-                 if need_sum else None)
-    else:
-        g_count = gseg(w) if need_count else None
-        g_sum = (jnp.clip(gseg(vclip * w), group_clip_lo, group_clip_hi)
-                 if need_sum else None)
-    g_norm = gseg(vnorm * w) if need_norm else None
-    g_norm_sq = gseg(vnorm * vnorm * w) if need_norm_sq else None
-    g_pk = _group_pk(s, num_partitions, gseg)
-    g_keep = gseg(keepg_start)
-    gw = (g_keep > 0).astype(dtype)
+    with jax.named_scope("group_reduce"):
+        if int_accumulate and sval_i is not None:
+            # Narrow-dtype group accumulation (gate:
+            # int_accumulation_plan). Counts and clipped sums are exact
+            # integers in both domains, so the int32 sums widen to the
+            # legacy float32 bits at the fold.
+            w_i = s.keep_row.astype(jnp.int32)
+            vclip_i = jnp.clip(
+                jnp.asarray(value_lo).astype(jnp.int32)
+                + sval_i * jnp.asarray(value_scale).astype(jnp.int32),
+                int_clip_lo, int_clip_hi)
+            g_count = gseg(w_i).astype(dtype) if need_count else None
+            g_sum = (jnp.clip(gseg(vclip_i * w_i).astype(dtype),
+                              group_clip_lo, group_clip_hi)
+                     if need_sum else None)
+        else:
+            g_count = gseg(w) if need_count else None
+            g_sum = (jnp.clip(gseg(vclip * w), group_clip_lo,
+                              group_clip_hi)
+                     if need_sum else None)
+        g_norm = gseg(vnorm * w) if need_norm else None
+        g_norm_sq = gseg(vnorm * vnorm * w) if need_norm_sq else None
+        g_pk = _group_pk(s, num_partitions, gseg)
+        g_keep = gseg(keepg_start)
+        gw = (g_keep > 0).astype(dtype)
 
     # -- kept groups -> per-partition accumulators -------------------------
     g_pk_safe = jnp.where(g_keep > 0, g_pk, 0).astype(jnp.int32)
     pseg = functools.partial(jax.ops.segment_sum,
                              segment_ids=g_pk_safe,
                              num_segments=num_partitions)
-    return PartitionAccumulators(
-        pid_count=pseg(gw),
-        count=pseg(g_count * gw) if need_count else zeros,
-        sum=pseg(g_sum * gw) if need_sum else zeros,
-        norm_sum=pseg(g_norm * gw) if need_norm else zeros,
-        norm_sq_sum=pseg(g_norm_sq * gw) if need_norm_sq else zeros,
-    )
+    with jax.named_scope("scatter"):
+        return PartitionAccumulators(
+            pid_count=pseg(gw),
+            count=pseg(g_count * gw) if need_count else zeros,
+            sum=pseg(g_sum * gw) if need_sum else zeros,
+            norm_sum=pseg(g_norm * gw) if need_norm else zeros,
+            norm_sq_sum=pseg(g_norm_sq * gw) if need_norm_sq else zeros,
+        )
 
 
 class CompactGroups(NamedTuple):
@@ -1379,22 +1390,27 @@ def bound_and_aggregate_compact(key: jax.Array,
     n = pid.shape[0]
     # Same trace-time sampler dispatch as bound_and_aggregate (shared
     # _dispatch_sampler) so the sampling decisions replay bitwise.
-    s = _dispatch_sampler(
-        key, pid, pk, valid, linf_cap, l0_cap, l1_cap,
-        num_partitions=num_partitions, max_segments=max_segments,
-        pid_sorted=pid_sorted, tile_rows=tile_rows, tile_slack=tile_slack,
-        hash_bins=hash_bins, hash_bin_rows=hash_bin_rows,
-        value=_narrow_sort_value(value, value_is_index, value_sort_bits))
+    with jax.named_scope("group_sort"):
+        s = _dispatch_sampler(
+            key, pid, pk, valid, linf_cap, l0_cap, l1_cap,
+            num_partitions=num_partitions, max_segments=max_segments,
+            pid_sorted=pid_sorted, tile_rows=tile_rows,
+            tile_slack=tile_slack, hash_bins=hash_bins,
+            hash_bin_rows=hash_bin_rows,
+            value=_narrow_sort_value(value, value_is_index,
+                                     value_sort_bits))
     if isinstance(s, BinnedRows):
-        return _hash_compact_groups(
-            s, pk, num_partitions=num_partitions, max_groups=max_groups,
-            row_clip_lo=row_clip_lo, row_clip_hi=row_clip_hi,
-            middle=middle, group_clip_lo=group_clip_lo,
-            group_clip_hi=group_clip_hi, need_count=need_count,
-            need_sum=need_sum, need_norm=need_norm,
-            need_norm_sq=need_norm_sq, has_group_clip=has_group_clip,
-            value_is_index=value_is_index, value_lo=value_lo,
-            value_scale=value_scale)
+        with jax.named_scope("group_reduce"):
+            return _hash_compact_groups(
+                s, pk, num_partitions=num_partitions,
+                max_groups=max_groups, row_clip_lo=row_clip_lo,
+                row_clip_hi=row_clip_hi, middle=middle,
+                group_clip_lo=group_clip_lo, group_clip_hi=group_clip_hi,
+                need_count=need_count, need_sum=need_sum,
+                need_norm=need_norm, need_norm_sq=need_norm_sq,
+                has_group_clip=has_group_clip,
+                value_is_index=value_is_index, value_lo=value_lo,
+                value_scale=value_scale)
     sval, sval_i = _widen_sorted_value(s.sval, value_is_index, value_lo,
                                        value_scale)
 
@@ -1408,27 +1424,28 @@ def bound_and_aggregate_compact(key: jax.Array,
                              num_segments=n,
                              indices_are_sorted=True)
     zeros_n = jnp.zeros((n,), dtype=dtype)
-    if int_accumulate and sval_i is not None:
-        # Same narrow-dtype group accumulation as bound_and_aggregate
-        # (gate: int_accumulation_plan; bit-identical widening).
-        w_i = s.keep_row.astype(jnp.int32)
-        vclip_i = jnp.clip(
-            jnp.asarray(value_lo).astype(jnp.int32)
-            + sval_i * jnp.asarray(value_scale).astype(jnp.int32),
-            int_clip_lo, int_clip_hi)
-        g_count = gseg(w_i).astype(dtype) if need_count else None
-        g_sum = gseg(vclip_i * w_i).astype(dtype) if need_sum else None
-    else:
-        g_count = gseg(w) if need_count else None
-        g_sum = gseg(vclip * w) if need_sum else None
-    if need_sum and has_group_clip:
-        g_sum = jnp.clip(g_sum, group_clip_lo, group_clip_hi)
-    g_norm = gseg(vnorm * w) if need_norm else None
-    g_norm_sq = gseg(vnorm * vnorm * w) if need_norm_sq else None
-    g_pk = _group_pk(s, num_partitions, gseg)
-    g_keep = gseg(keepg_start)
-    gw = (g_keep > 0).astype(dtype)
-    g_pk_safe = jnp.where(g_keep > 0, g_pk, 0).astype(jnp.int32)
+    with jax.named_scope("group_reduce"):
+        if int_accumulate and sval_i is not None:
+            # Same narrow-dtype group accumulation as bound_and_aggregate
+            # (gate: int_accumulation_plan; bit-identical widening).
+            w_i = s.keep_row.astype(jnp.int32)
+            vclip_i = jnp.clip(
+                jnp.asarray(value_lo).astype(jnp.int32)
+                + sval_i * jnp.asarray(value_scale).astype(jnp.int32),
+                int_clip_lo, int_clip_hi)
+            g_count = gseg(w_i).astype(dtype) if need_count else None
+            g_sum = gseg(vclip_i * w_i).astype(dtype) if need_sum else None
+        else:
+            g_count = gseg(w) if need_count else None
+            g_sum = gseg(vclip * w) if need_sum else None
+        if need_sum and has_group_clip:
+            g_sum = jnp.clip(g_sum, group_clip_lo, group_clip_hi)
+        g_norm = gseg(vnorm * w) if need_norm else None
+        g_norm_sq = gseg(vnorm * vnorm * w) if need_norm_sq else None
+        g_pk = _group_pk(s, num_partitions, gseg)
+        g_keep = gseg(keepg_start)
+        gw = (g_keep > 0).astype(dtype)
+        g_pk_safe = jnp.where(g_keep > 0, g_pk, 0).astype(jnp.int32)
 
     # The same scatter operands the legacy partition pass would feed
     # (value * gw, in group order) — compacted instead of scattered.
@@ -1438,9 +1455,11 @@ def bound_and_aggregate_compact(key: jax.Array,
             g_norm * gw if need_norm else zeros_n,
             g_norm_sq * gw if need_norm_sq else zeros_n)
 
-    return _compact_from_groups(g_keep > 0, g_pk_safe, cols,
-                                max_groups=max_groups,
-                                num_partitions=num_partitions, dtype=dtype)
+    with jax.named_scope("compact"):
+        return _compact_from_groups(g_keep > 0, g_pk_safe, cols,
+                                    max_groups=max_groups,
+                                    num_partitions=num_partitions,
+                                    dtype=dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("num_partitions",
@@ -1470,15 +1489,16 @@ def merge_compact_chunks(accs: PartitionAccumulators,
     def scat(base, col):
         return base.at[flat_pk].add(col.reshape(-1), mode="drop")
 
-    return PartitionAccumulators(
-        pid_count=scat(accs.pid_count, pid_count),
-        count=scat(accs.count, count) if need_flags[0] else accs.count,
-        sum=scat(accs.sum, sum_) if need_flags[1] else accs.sum,
-        norm_sum=(scat(accs.norm_sum, norm_sum)
-                  if need_flags[2] else accs.norm_sum),
-        norm_sq_sum=(scat(accs.norm_sq_sum, norm_sq_sum)
-                     if need_flags[3] else accs.norm_sq_sum),
-    )
+    with jax.named_scope("scatter"):
+        return PartitionAccumulators(
+            pid_count=scat(accs.pid_count, pid_count),
+            count=scat(accs.count, count) if need_flags[0] else accs.count,
+            sum=scat(accs.sum, sum_) if need_flags[1] else accs.sum,
+            norm_sum=(scat(accs.norm_sum, norm_sum)
+                      if need_flags[2] else accs.norm_sum),
+            norm_sq_sum=(scat(accs.norm_sq_sum, norm_sq_sum)
+                         if need_flags[3] else accs.norm_sq_sum),
+        )
 
 
 def compact_group_bound(cap: int, ucap: int, l0_cap) -> Optional[int]:

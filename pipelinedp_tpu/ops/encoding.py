@@ -20,6 +20,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from pipelinedp_tpu.obs import trace as obs_trace
+
 
 class Vocabulary:
     """Bidirectional key <-> dense id mapping.
@@ -243,36 +245,42 @@ def encode_columns(
     becomes its own privacy unit). With ``factorize_pid=False`` integer
     privacy ids skip factorization entirely (returned shifted-to-zero with
     pid_vocab=None) — the kernels never need dense pid ids, only equality.
+
+    One child span per column pass of the engine's ``dp/encode``:
+    ``dp/encode/pk``, ``dp/encode/pid`` and ``dp/encode/value``.
     """
     pk_col = np.asarray(pk_col)
     if pid_col is not None:
         pid_col = np.asarray(pid_col)
-    if public_partitions is not None:
-        pk_vocab = Vocabulary(public_partitions)
-        pk_ids = _lookup_ids(pk_col, pk_vocab)
-        keep = pk_ids >= 0
-        pk_ids = pk_ids[keep]
-        if pid_col is not None:
-            pid_col = pid_col[keep]
-        if value_col is not None:
-            value_col = np.asarray(value_col)[keep]
-    else:
-        pk_ids, pk_uniques = _factorize(pk_col)
-        pk_vocab = Vocabulary.from_unique(pk_uniques)
-    if pid_col is None:
-        pid_ids = np.arange(len(pk_ids), dtype=np.int32)
-        pid_vocab = Vocabulary.from_unique(np.arange(len(pk_ids)))
-    else:
-        pid_ids = None if factorize_pid else _pid_passthrough(pid_col)
-        if pid_ids is not None:
-            pid_vocab = None
+    with obs_trace.span("dp/encode/pk", public=public_partitions is not None):
+        if public_partitions is not None:
+            pk_vocab = Vocabulary(public_partitions)
+            pk_ids = _lookup_ids(pk_col, pk_vocab)
+            keep = pk_ids >= 0
+            pk_ids = pk_ids[keep]
+            if pid_col is not None:
+                pid_col = pid_col[keep]
+            if value_col is not None:
+                value_col = np.asarray(value_col)[keep]
         else:
-            pid_ids, pid_uniques = _factorize(pid_col)
-            pid_vocab = Vocabulary.from_unique(pid_uniques)
-    value_arr = _value_array(value_col, len(pk_ids), vector_size)
-    return (pid_ids.astype(np.int32, copy=False),
-            pk_ids.astype(np.int32, copy=False), value_arr, pid_vocab,
-            pk_vocab)
+            pk_ids, pk_uniques = _factorize(pk_col)
+            pk_vocab = Vocabulary.from_unique(pk_uniques)
+    with obs_trace.span("dp/encode/pid"):
+        if pid_col is None:
+            pid_ids = np.arange(len(pk_ids), dtype=np.int32)
+            pid_vocab = Vocabulary.from_unique(np.arange(len(pk_ids)))
+        else:
+            pid_ids = None if factorize_pid else _pid_passthrough(pid_col)
+            if pid_ids is not None:
+                pid_vocab = None
+            else:
+                pid_ids, pid_uniques = _factorize(pid_col)
+                pid_vocab = Vocabulary.from_unique(pid_uniques)
+        pid_ids = pid_ids.astype(np.int32, copy=False)
+    with obs_trace.span("dp/encode/value"):
+        value_arr = _value_array(value_col, len(pk_ids), vector_size)
+    return (pid_ids, pk_ids.astype(np.int32, copy=False), value_arr,
+            pid_vocab, pk_vocab)
 
 
 def _value_array(value_col, n: int,

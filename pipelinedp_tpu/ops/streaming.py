@@ -340,10 +340,11 @@ def _chunk_step(key, buf, n_valid, accs, linf_cap, l0_cap, row_clip_lo,
     Chunks are pid-disjoint, so the optional L1 (max_contributions) sample
     inside the kernel is exact per chunk.
     """
-    pid = _unpack_ints(buf, 0, bytes_pid)
-    pk = _unpack_ints(buf, bytes_pid, bytes_pk)
-    value = _unpack_value(buf, bytes_pid + bytes_pk, value_f16)
-    valid = jnp.arange(buf.shape[0], dtype=jnp.int32) < n_valid
+    with jax.named_scope("decode"):
+        pid = _unpack_ints(buf, 0, bytes_pid)
+        pk = _unpack_ints(buf, bytes_pid, bytes_pk)
+        value = _unpack_value(buf, bytes_pid + bytes_pk, value_f16)
+        valid = jnp.arange(buf.shape[0], dtype=jnp.int32) < n_valid
     chunk_accs = columnar.bound_and_aggregate(
         key, pid, pk, value, valid,
         num_partitions=num_partitions,
@@ -368,14 +369,21 @@ def _decode_for_kernel(row, n_valid, n_uniq, fmt):
     narrow int32 plane index through the kernel's sort (widened after it
     with the identical reconstruction expression — bit-for-bit the same
     released values); other modes decode to float32 as before. Returns
-    (pid, pk, value, valid, value_kwargs-for-the-kernel)."""
+    (pid, pk, value, valid, value_kwargs-for-the-kernel).
+
+    The chunk programs name their phases with ``jax.named_scope`` —
+    ``decode`` here, then ``group_sort`` (``group_sort/sample``),
+    ``group_reduce``, ``compact`` and ``scatter`` in ops/columnar.py — so
+    a device trace splits a chunk program's time by phase; the jit names
+    stay what the benchmark's readers match."""
     value_as_index = (fmt.value.mode == wirecodec.VALUE_PLANES
                       and fmt.sort_value_narrow)
-    pid, pk, value, valid = wirecodec.decode_bucket(
-        row, n_valid, n_uniq, fmt, value_as_index=value_as_index)
-    if value is None:
-        value = jnp.zeros((fmt.cap,), dtype=jnp.float32)
-        value_as_index = False
+    with jax.named_scope("decode"):
+        pid, pk, value, valid = wirecodec.decode_bucket(
+            row, n_valid, n_uniq, fmt, value_as_index=value_as_index)
+        if value is None:
+            value = jnp.zeros((fmt.cap,), dtype=jnp.float32)
+            value_as_index = False
     kwargs = dict(
         tile_rows=fmt.tile_rows if fmt.pid_sorted else 0,
         tile_slack=fmt.tile_slack if fmt.pid_sorted else 0,
@@ -864,7 +872,8 @@ def stream_bound_and_aggregate(
                                 "wirecodec: prep-time RLE entry "
                                 "counts disagree with the sorted "
                                 "buckets")
-                    return enc.emit_range(s0, s1, fmt)
+                    with obs_trace.span("dp/wire_emit"):
+                        return enc.emit_range(s0, s1, fmt)
 
                 step_chunk, compact_step, merge_fn = build_steps(
                     fmt, int_clip, sort_stats)
@@ -1327,6 +1336,7 @@ class _IngestPlacement(driver_lib.DevicePlacement):
     prefetch_prefix = "pdp-ingest-prefetch"
     degradable = False
     donates = False
+    ships_host_bytes = False
 
     def init_state(self):
         return None, None
@@ -1445,7 +1455,8 @@ def ingest_resident_wire(pid: np.ndarray,
                         raise RuntimeError(
                             "wirecodec: prep-time RLE entry counts "
                             "disagree with the sorted buckets")
-                return enc.emit_range(s0, s1, fmt)
+                with obs_trace.span("dp/wire_emit"):
+                    return enc.emit_range(s0, s1, fmt)
 
             slab = np.zeros((k, fmt.width), dtype=np.uint8)
 
@@ -1489,6 +1500,10 @@ class _ResidentReplayPlacement(_SingleDevicePlacement):
     def __init__(self, device_slab=None, **kw):
         super().__init__(**kw)
         self._device_slab = device_slab
+
+    @property
+    def ships_host_bytes(self) -> bool:
+        return self._device_slab is None
 
     def transfer(self, slab, s0, s1):
         if self._device_slab is not None:

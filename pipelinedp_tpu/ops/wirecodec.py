@@ -49,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from pipelinedp_tpu import profiler
+from pipelinedp_tpu.obs import trace as obs_trace
 
 # Same Knuth multiplicative hash as streaming.py's bucketing (buckets must
 # stay pid-disjoint and identical across the codec and the legacy packer).
@@ -894,11 +895,18 @@ def make_encoder(pid: np.ndarray, pk, value, *, num_partitions: int, k: int,
     chunked host verification of plan_and_index runs for the numpy
     fallback.
 
+    Each pass is a child span of the callers' ``dp/wire_prep``:
+    ``dp/wire_prep/pid_range`` (pid min/max), ``.../value_plan`` (the
+    sample plan, or the full plan on the re-plan and numpy paths),
+    ``.../native_scan`` (the native scatter / RLE-count pass) and
+    ``.../fallback_stats`` (numpy RLE entry counts).
+
     Returns (enc_or_None, EncodeInfo).
     """
     pid = np.asarray(pid)
-    pid_lo = int(pid.min())
-    pid_span = int(pid.max()) - pid_lo
+    with obs_trace.span("dp/wire_prep/pid_range"):
+        pid_lo = int(pid.min())
+        pid_span = int(pid.max()) - pid_lo
     if pid_span >= np.iinfo(np.int32).max - 1:
         # The kernel reserves INT32_MAX as its padding sentinel; a shifted
         # pid colliding with it would be silently dropped.
@@ -921,9 +929,23 @@ def make_encoder(pid: np.ndarray, pk, value, *, num_partitions: int, k: int,
                           bits_pid=bits_pid, entry_counts=entry_counts,
                           max_run=max_run)
 
+    def full_plan():
+        with obs_trace.span("dp/wire_prep/value_plan", full=True):
+            return plan_and_index(value, value_f16)
+
+    def numpy_stats():
+        with obs_trace.span("dp/wire_prep/fallback_stats"):
+            return rle_entry_stats_numpy(pid, pid_lo, k, pid_span)
+
+    def native_scan(plan, vidx, **kw):
+        with obs_trace.span("dp/wire_prep/native_scan"):
+            return NativeRleEncoder.create(pid, pk, value, vidx,
+                                           pid_lo=pid_lo, k=k, plan=plan,
+                                           pid_span=pid_span, **kw)
+
     def fallback_info():
-        plan, vidx = plan_and_index(value, value_f16)
-        entries, max_run = rle_entry_stats_numpy(pid, pid_lo, k, pid_span)
+        plan, vidx = full_plan()
+        entries, max_run = numpy_stats()
         return info_for(plan, vidx, entries, max_run)
 
     if _load_packer() is None:
@@ -931,23 +953,21 @@ def make_encoder(pid: np.ndarray, pk, value, *, num_partitions: int, k: int,
         # host (and must not pay the sample pass twice).
         return None, fallback_info()
 
-    tentative = _sample_plan(value, value_f16)
+    with obs_trace.span("dp/wire_prep/value_plan", full=False):
+        tentative = _sample_plan(value, value_f16)
     status: dict = {}
-    enc = NativeRleEncoder.create(pid, pk, value, None, pid_lo=pid_lo, k=k,
-                                  plan=tentative, inline_vidx=True,
-                                  out_status=status, pid_span=pid_span)
+    enc = native_scan(tentative, None, inline_vidx=True, out_status=status)
     if enc is not None:
         return enc, info_for(enc.plan, None, enc.entry_counts, enc.max_run)
     if status.get("inline_failed"):
         # The sample-chosen scale failed the full array: re-plan with the
         # full chunked host verification (which tries the other scales)
         # and retry — rare, and only costs the fallback pass.
-        plan, vidx = plan_and_index(value, value_f16)
-        enc = NativeRleEncoder.create(pid, pk, value, vidx, pid_lo=pid_lo,
-                                      k=k, plan=plan, pid_span=pid_span)
+        plan, vidx = full_plan()
+        enc = native_scan(plan, vidx)
         if enc is not None:
             return enc, info_for(plan, vidx, enc.entry_counts, enc.max_run)
-        entries, max_run = rle_entry_stats_numpy(pid, pid_lo, k, pid_span)
+        entries, max_run = numpy_stats()
         return None, info_for(plan, vidx, entries, max_run)
     return None, fallback_info()
 

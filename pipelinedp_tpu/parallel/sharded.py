@@ -768,6 +768,7 @@ def stream_bound_and_aggregate(mesh: Mesh,
     """
     import dataclasses
 
+    from pipelinedp_tpu import profiler
     from pipelinedp_tpu.ops import streaming, wirecodec
 
     if resume_from is not None:
@@ -792,9 +793,10 @@ def stream_bound_and_aggregate(mesh: Mesh,
     k = n_c * n_dev
     # Shared encode prologue with ops/streaming.py (pid-span validation,
     # width/bit planning, value plan, pid wire mode, native encoder).
-    enc, info = wirecodec.make_encoder(
-        pid, pk, value, num_partitions=num_partitions, k=k,
-        value_transfer_dtype=value_transfer_dtype)
+    with profiler.stage("dp/wire_prep"):
+        enc, info = wirecodec.make_encoder(
+            pid, pk, value, num_partitions=num_partitions, k=k,
+            value_transfer_dtype=value_transfer_dtype)
     if enc is not None:
         with enc:
             counts = enc.counts
@@ -1301,6 +1303,7 @@ def _drive_codec_chunks(mesh, key, emit, counts, n_uniq, fmt, n_c, n_dev,
         fmt_desc=repr(("mesh", n_dev, fmt)),
         counts=counts,
         n_uniq=n_uniq,
+        buckets_per_chunk=n_dev,
         scatter_passes=scatter_passes,
         quantile=False,
         data_digest_fn=data_digest_fn,

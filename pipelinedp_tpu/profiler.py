@@ -3,8 +3,7 @@
 A capability the reference lacks (SURVEY.md §5 — its only observability is
 the explain-computation report): wrap any engine call in
 ``with profiler.profile("/tmp/trace"):`` and open the result in
-TensorBoard/Perfetto; the engine's stages show up as named trace spans via
-``stage(...)`` annotations.
+TensorBoard/Perfetto; the engine's stages show up as named trace spans.
 
     import pipelinedp_tpu as pdp
     from pipelinedp_tpu import profiler
@@ -14,8 +13,11 @@ TensorBoard/Perfetto; the engine's stages show up as named trace spans via
         accountant.compute_budgets()
         result.to_columns()
 
-Annotations are no-ops when no trace is active, so they stay in the engine
-permanently.
+Spans are ``obs.trace.span`` (the one span system: profiler annotation,
+stage-time sinks and, when installed, the obs tracer); ``stage(name)`` is
+its attribute-free delegate, and the sink helpers below are re-exported
+from there. Annotations are near-free when no trace is active, so they
+stay in the engine permanently.
 
 Thread-safety contract (PR 5's pool + lock machinery, checked by dplint
 DPL008): the engine's worker pools (slab prefetch, encode workers) call
@@ -23,89 +25,35 @@ into this module from pool threads, so entry points are classified:
 
   * **pool-safe**: ``stage()``, ``current_sinks()``, ``adopt_sinks()``,
     ``count_event()``, ``event_count()``, ``event_counts()`` — sink
-    mutation funnels through ``_add_stage_time`` under ``_sink_lock``,
-    counters through the obs metrics registry's single lock, and the
-    sink *list* is
-    thread-local (``adopt_sinks`` installs the parent's collectors into
-    the worker's own ``_collect`` slot, never sharing the list object
-    across threads).
+    mutation funnels through ``obs.trace._add_stage_time`` under its
+    ``_sink_lock``, counters through the obs metrics registry's single
+    lock, and the sink *list* is thread-local (``adopt_sinks`` installs
+    the parent's collectors into the worker's own slot, never sharing
+    the list object across threads).
   * **owning-thread only**: ``collect_stage_times()`` (registers the
     sink dict on the calling thread; workers must join via
     ``adopt_sinks(current_sinks())`` captured on the parent),
     ``profile()`` / ``reset_events()`` (process-global trace/counter
     state; call from the driver thread, not from workers).
 
-Set ``PIPELINEDP_TPU_DEBUG_LOCKS=1`` (validated via
-``native.loader.env_int``) to assert the sink lock is held around every
-sink mutation — a cheap canary for refactors that bypass
-``_add_stage_time``.
+Compile telemetry: importing this module registers one ``jax.monitoring``
+listener on ``/jax/core/compile/backend_compile_duration`` (a compile or
+a persistent-cache load of one program), which counts ``jit/compiles``
+and observes ``pipelinedp_tpu_compile_seconds{program=<jit name>}``.
 """
 
 from __future__ import annotations
 
 import contextlib
-import threading
-import time
 from typing import Dict, Iterator, Optional
 
 import jax
+import jax.monitoring
 
 from pipelinedp_tpu.obs import metrics as obs_metrics
-
-# Active wall-clock stage collectors (see collect_stage_times). Thread-local
-# so concurrent engines don't interleave their phase budgets; worker pools
-# an engine spawns (slab prefetch, encode workers) join their parent's
-# collectors explicitly via adopt_sinks(current_sinks()). Sink updates are
-# guarded by _sink_lock — multiple threads record into one sink dict.
-_collect = threading.local()
-_sink_lock = threading.Lock()
-
-
-# Debug-locks env knob: name kept here, validation delegated to the
-# shared loader.env_int helper (unset/empty -> off; junk raises).
-DEBUG_LOCKS_ENV = "PIPELINEDP_TPU_DEBUG_LOCKS"
-
-
-def _debug_locks() -> bool:
-    """Re-read per call so tests can flip the env between stages."""
-    from pipelinedp_tpu.native import loader
-    return bool(loader.env_int(DEBUG_LOCKS_ENV, 0, 0, 1))
-
-
-def current_sinks() -> list:
-    """This thread's active stage-time sinks (share with adopt_sinks).
-    Pool-safe: returns a fresh list snapshot of thread-local state."""
-    return list(getattr(_collect, "sinks", None) or ())
-
-
-def _add_stage_time(sinks, name: str, dt: float) -> None:
-    """Thread-safe accumulation of one stage timing into the sinks —
-    the single place sink dicts are mutated; every caller (any thread)
-    goes through the lock acquired here."""
-    with _sink_lock:
-        if _debug_locks():
-            assert _sink_lock.locked(), (
-                "sink mutation outside _sink_lock — a refactor bypassed "
-                "_add_stage_time's locking")
-        for sink in sinks:
-            sink[name] = sink.get(name, 0.0) + dt
-
-
-@contextlib.contextmanager
-def adopt_sinks(sinks) -> "Iterator[None]":
-    """Installs a parent thread's collectors into this (worker) thread so
-    its stage() timings merge into the parent's collect_stage_times()
-    dict. Restores the worker's previous sinks on exit; safe to nest.
-    Pool-safe: the handoff half of the cross-thread protocol — capture
-    ``current_sinks()`` on the parent, enter this on the worker."""
-    prev = getattr(_collect, "sinks", None)
-    mine = list(prev or ())
-    mine.extend(s for s in sinks if s not in mine)
-    _collect.sinks = mine
-    try:
-        yield
-    finally:
-        _collect.sinks = prev
+from pipelinedp_tpu.obs import trace as obs_trace
+from pipelinedp_tpu.obs.trace import (  # noqa: F401 — re-exported API
+    adopt_sinks, collect_stage_times, current_sinks)
 
 # Global named counters: compile/trace/cache telemetry (ops/finalize uses
 # them to count epilogue retraces and executable-cache hits). Unlike stage
@@ -160,56 +108,24 @@ def profile(logdir: str,
         jax.profiler.stop_trace()
 
 
-@contextlib.contextmanager
-def stage(name: str) -> Iterator[None]:
+def stage(name: str):
     """Names the enclosed host block (and its dispatched device work) in
-    the trace; free when no trace is active. When a collect_stage_times()
-    block is active, also accumulates the stage's host wall time."""
-    sinks = getattr(_collect, "sinks", None)
-    if sinks:
-        t0 = time.perf_counter()
-        try:
-            with jax.profiler.TraceAnnotation(name):
-                yield
-        finally:
-            _add_stage_time(sinks, name, time.perf_counter() - t0)
+    the trace and, inside collect_stage_times(), times it: an
+    ``obs.trace.span`` without attributes."""
+    return obs_trace.span(name)
+
+
+# The compile listener (module docstring). Registered once per process.
+EVENT_COMPILES = "jit/compiles"
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _on_compile(event: str, duration_s: float, **kwargs) -> None:
+    if event != COMPILE_EVENT:
         return
-    with jax.profiler.TraceAnnotation(name):
-        yield
+    count_event(EVENT_COMPILES)
+    obs_metrics.compile_seconds().observe(
+        duration_s, program=str(kwargs.get("fun_name", "")))
 
 
-@contextlib.contextmanager
-def collect_stage_times() -> Iterator[Dict[str, float]]:
-    """Collects per-stage host wall seconds for the enclosed block.
-
-    Yields a dict that fills as stages complete: {stage_name: seconds},
-    summed over re-entries. Note these are HOST wall times — a stage that
-    only dispatches async device work (device_put, jitted kernels) is
-    cheap here even when the device is busy long after; that asymmetry is
-    exactly what the bench's overlap report keys off.
-
-    Owning-thread only: registers the sink on the *calling* thread's
-    collector list; pool workers join through
-    ``adopt_sinks(current_sinks())`` captured on this thread instead of
-    entering this context themselves.
-    """
-    sink: Dict[str, float] = {}
-    sinks = getattr(_collect, "sinks", None)
-    if sinks is None:
-        sinks = _collect.sinks = []
-    sinks.append(sink)
-    try:
-        yield sink
-    finally:
-        sinks.remove(sink)
-
-
-def annotate_function(fn, name: Optional[str] = None):
-    """Decorator form of stage()."""
-    return jax.profiler.annotate_function(fn, name=name)
-
-
-def device_memory_profile(path: str) -> None:
-    """Writes a device memory profile (pprof format) to path."""
-    with open(path, "wb") as f:
-        f.write(jax.profiler.device_memory_profile())
+jax.monitoring.register_event_duration_secs_listener(_on_compile)

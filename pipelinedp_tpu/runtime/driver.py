@@ -82,6 +82,19 @@ EVENT_HANGS = "runtime/hangs_detected"
 EVENT_PARTITION_SCATTERS = "ops/partition_scatter_passes"
 EVENT_COMPACT_CHUNKS = "ops/compact_chunk_emits"
 
+# Per-shipped-window wire counters: the bytes of each host slab handed to
+# DevicePlacement.transfer (padding included) and the input rows of that
+# window's chunks. Counted only by placements that ship host bytes; a
+# retried window ships, and counts, again.
+EVENT_WIRE_BYTES = "ops/wire_bytes"
+EVENT_WIRE_ROWS = "ops/wire_rows"
+
+
+def count_wire(nbytes: int, rows: int) -> None:
+    """Credits one shipped host payload to the wire counters."""
+    profiler.count_event(EVENT_WIRE_BYTES, int(nbytes))
+    profiler.count_event(EVENT_WIRE_ROWS, int(rows))
+
 
 class DevicePlacement(abc.ABC):
     """Where slabs land and how chunk results fold, for one topology.
@@ -93,8 +106,8 @@ class DevicePlacement(abc.ABC):
     implements this same interface.
 
     Class attributes:
-      stage_prefix: profiler stage name prefix per slab window (the
-        window's first chunk index is appended).
+      stage_prefix: span name prefix per slab window (the window's first
+        chunk index is appended).
       prefetch_prefix: thread-name prefix for the lookahead encode pool.
       degradable: device OOM halves the slab window (single-device);
         False re-issues the window as a counted retry (mesh — chunk
@@ -105,6 +118,10 @@ class DevicePlacement(abc.ABC):
       compact: chunk results are compact per-group columns collected in
         ``pending`` and folded by :meth:`merge_pending` at checkpoints
         and once at the end, instead of dense per-chunk scatters.
+      ships_host_bytes: :meth:`transfer` copies the host slab to the
+        device, so the driver counts it in ``ops/wire_bytes`` /
+        ``ops/wire_rows`` (False where the wire is already on the device
+        or nothing lands on one).
     """
 
     stage_prefix: str = "dp/stream_slab_"
@@ -112,6 +129,7 @@ class DevicePlacement(abc.ABC):
     degradable: bool = False
     donates: bool = False
     compact: bool = False
+    ships_host_bytes: bool = True
 
     @abc.abstractmethod
     def init_state(self) -> Tuple[Any, Any]:
@@ -176,12 +194,17 @@ class SlabPlan:
     idempotent per ``(s0, s1)`` range — retries, OOM-degraded windows
     and resumes may prepare (and therefore retain) a range more than
     once, and degradations change the window boundaries.
+
+    buckets_per_chunk is how many entries of ``counts`` one chunk spans
+    (the mesh ships n_dev codec buckets per chunk); the wire-rows counter
+    sums a window's entries.
     """
     n_chunks: int
     window_chunks: int
     fmt_desc: str
     counts: Any
     n_uniq: Optional[Any]
+    buckets_per_chunk: int = 1
     scatter_passes: int = 5
     quantile: bool = False
     data_digest_fn: Optional[Callable[[], str]] = None
@@ -252,9 +275,8 @@ class SlabDriver:
                     obs_trace.event("resume", next_chunk=cursor)
 
         def save_checkpoint(next_chunk, accs, qhist):
-            import time as time_lib
-            t0 = time_lib.perf_counter()
-            with obs_trace.span("driver/checkpoint",
+            t0 = time.perf_counter()
+            with obs_trace.span("dp/checkpoint",
                                 next_chunk=int(next_chunk)):
                 host_accs, host_q = placement.snapshot(accs, qhist)
                 cp = checkpoint_lib.StreamCheckpoint(
@@ -265,7 +287,7 @@ class SlabDriver:
                 cp_policy.store.save(cp)
             profiler.count_event(EVENT_CHECKPOINT_BYTES, cp.nbytes())
             obs_metrics.checkpoint_write_seconds().observe(
-                time_lib.perf_counter() - t0)
+                time.perf_counter() - t0)
 
         compact = placement.compact
         donating = placement.donates and not compact
@@ -288,15 +310,16 @@ class SlabDriver:
         depth = plan.prefetch_depth
         executor = None
         inflight = {}
-        parent_sinks = profiler.current_sinks()
+        parent_sinks = obs_trace.current_sinks()
         parent_span = obs_trace.current()
+        ships = placement.ships_host_bytes
+        per_chunk = max(1, plan.buckets_per_chunk)
 
         def prefetch_call(a, b):
-            with profiler.adopt_sinks(parent_sinks), \
+            with obs_trace.adopt_sinks(parent_sinks), \
                     obs_trace.attach(parent_span):
-                with profiler.stage("dp/wire_sort_parallel"), \
-                        obs_trace.span("driver/prefetch_encode",
-                                       chunk0=a, chunk1=b):
+                with obs_trace.span("dp/wire_sort_parallel", chunk0=a,
+                                    chunk1=b):
                     return self._prepare_slab(a, b)
 
         def discard_inflight():
@@ -324,12 +347,14 @@ class SlabDriver:
                 in_dispatch = False
                 w0, t_w0 = cursor, time.perf_counter()
                 try:
-                    with profiler.stage(
-                            f"{placement.stage_prefix}{cursor}"), \
-                            obs_trace.span("driver/window", chunk0=cursor,
-                                           chunk1=s1, attempt=failures):
+                    with obs_trace.span(
+                            f"{placement.stage_prefix}{cursor}",
+                            chunk0=cursor, chunk1=s1, attempt=failures):
                         fut = inflight.pop((cursor, s1), None)
-                        with obs_trace.span("driver/encode",
+                        # Main thread until the host slab is ready: the
+                        # wait on the prefetch thread, or the inline
+                        # encode when nothing was prefetched.
+                        with obs_trace.span("dp/slab_wait",
                                             prefetched=fut is not None):
                             slab = (fut.result() if fut is not None
                                     else self._prepare_slab(cursor, s1))
@@ -358,13 +383,16 @@ class SlabDriver:
                                 injector.check("transfer", this_window)
                             return placement.transfer(slab, s0, s1)
 
-                        with obs_trace.span("driver/transfer"):
+                        with obs_trace.span("dp/slab_transfer"):
                             payload = guarded(f"transfer of window "
                                               f"[{s0}, {s1})", do_transfer)
+                        if ships:
+                            count_wire(slab.nbytes, plan.counts[
+                                s0 * per_chunk:s1 * per_chunk].sum())
                         if injector is not None:
                             injector.check("kernel", this_window)
                         for c in range(s0, s1):
-                            with obs_trace.span("driver/dispatch",
+                            with obs_trace.span("dp/slab_dispatch",
                                                 chunk=c):
                                 if compact:
                                     pending.append(guarded(
@@ -392,7 +420,7 @@ class SlabDriver:
                             # here means dispatched-but-unmaterialized
                             # state: only a checkpoint is trustworthy.
                             in_dispatch = True
-                            with obs_trace.span("driver/sync"):
+                            with obs_trace.span("dp/slab_sync"):
                                 wd.call("window sync",
                                         lambda: placement.sync(accs, qhist,
                                                                pending))

@@ -949,9 +949,8 @@ class LiveDatasetSession(DatasetSession):
             self._encode_wire(pid, pk, value)
 
     def _fold_union(self):
-        with profiler.stage("dp/ingest"), \
-                obs_trace.span("serving/fold", session=self._name,
-                               epochs=len(self._epochs)):
+        with obs_trace.span("dp/ingest", session=self._name,
+                            epochs=len(self._epochs)):
             return self._encode_wire(*self._union_rows())
 
     def _sweep_stale_bound_entries(self, old_fp: str) -> None:

@@ -314,8 +314,7 @@ class DatasetSession:
                           epilogue_cache=epilogue_cache,
                           resident_bytes=resident_bytes)
 
-        with profiler.stage("dp/ingest"), \
-                obs_trace.span("serving/ingest", session=name):
+        with obs_trace.span("dp/ingest", session=name):
             pid, pk, value, _, pk_vocab = encoding.encode_rows(
                 data, True, None, None,
                 public_partitions=self._public, factorize_pid=False)
@@ -762,10 +761,11 @@ class DatasetSession:
         evidence checkpoint resume uses to refuse mutated inputs)."""
         if self._source is None:
             return
-        digest = checkpoint_lib.array_digest(
-            np.asarray(self._source.pid), np.asarray(self._source.pk),
-            None if self._source.value is None else np.asarray(
-                self._source.value))
+        with obs_trace.span("dp/verify_source", session=self._name):
+            digest = checkpoint_lib.array_digest(
+                np.asarray(self._source.pid), np.asarray(self._source.pk),
+                None if self._source.value is None else np.asarray(
+                    self._source.value))
         if digest != self._source_digest:
             raise StaleDatasetError(
                 f"session {self._name!r}: the source columns changed "
@@ -1306,16 +1306,17 @@ class DatasetSession:
             dropped = int(keep.size) - kept
         token = finalize_ops.release_token(
             engine._key_stream.fingerprint(), engine._key_stream.counter)
-        self._audit.record(
-            session=self._name, tenant=tenant, token=str(token),
-            outcome=outcome,
-            mechanisms=[str(m) for m in params.metrics],
-            noise_kind=getattr(params.noise_kind, "value",
-                               str(params.noise_kind)),
-            epsilon=float(accountant.total_epsilon),
-            delta=float(accountant.total_delta),
-            partitions_kept=kept, partitions_dropped=dropped,
-            duration_s=duration_s, seed=seed, trace_id=qid)
+        with obs_trace.span("dp/audit_append", outcome=outcome):
+            self._audit.record(
+                session=self._name, tenant=tenant, token=str(token),
+                outcome=outcome,
+                mechanisms=[str(m) for m in params.metrics],
+                noise_kind=getattr(params.noise_kind, "value",
+                                   str(params.noise_kind)),
+                epsilon=float(accountant.total_epsilon),
+                delta=float(accountant.total_delta),
+                partitions_kept=kept, partitions_dropped=dropped,
+                duration_s=duration_s, seed=seed, trace_id=qid)
 
     def _maybe_capture(self, qid, root_span, outcome, duration_s,
                        deadline_s, cap_dir, cap_mark, cap_events0, *,

@@ -22,7 +22,7 @@ def record_released_stat(key, pid, pk, value, spec, n, span):
 def record_operational_metrics(histogram, n_chunks):
     # Timings and structural counts are operational, not private.
     t0 = time.perf_counter()
-    with obs_trace.span("driver/window", chunk0=0, chunk1=n_chunks):
+    with obs_trace.span("dp/stream_slab_0", chunk0=0, chunk1=n_chunks):
         pass
     histogram.observe(time.perf_counter() - t0)
 

@@ -198,8 +198,8 @@ class TestAcceptanceTraceAndExposition:
         events = doc["traceEvents"]
         names = {e["name"] for e in events if e["ph"] == "X"}
         assert {"serving/query", "serving/admission", "serving/replay",
-                "engine/finalize", "driver/window",
-                "driver/transfer"} <= names
+                "dp/finalize", "dp/replay_slab_0",
+                "dp/slab_transfer"} <= names
         # One root: the query; everything else parents into it.
         roots = [e for e in events if e["ph"] == "X"
                  and "parent_id" not in e["args"]]

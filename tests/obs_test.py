@@ -112,12 +112,16 @@ class TestTracer:
         assert span.attrs["error"] is True
 
     def test_disabled_is_shared_null_context(self):
+        # No tracer: a span records no Span, but it still annotates the
+        # profiler trace and feeds the stage sinks.
         trace_lib.shutdown()
-        ctx1 = trace_lib.span("x", a=1)
-        ctx2 = trace_lib.span("y")
-        assert ctx1 is ctx2  # the shared singleton: zero allocation
-        with ctx1 as span:
-            assert span is None
+        with trace_lib.collect_stage_times() as stages:
+            with trace_lib.span("x", a=1) as span:
+                assert span is None
+                assert trace_lib.current() is None
+            with trace_lib.span("y") as span:
+                assert span is None
+        assert set(stages) == {"x", "y"}
         trace_lib.event("nothing")  # no-op, no error
         assert trace_lib.current() is None
 

@@ -8,6 +8,7 @@ import pytest
 
 import pipelinedp_tpu as pdp
 from pipelinedp_tpu import profiler
+from pipelinedp_tpu.obs import trace as trace_lib
 
 
 class TestProfiler:
@@ -52,7 +53,7 @@ class TestThreadSafety:
 
             def worker():
                 for _ in range(n_iters):
-                    profiler._add_stage_time(sinks, "hammer", 1.0)
+                    trace_lib._add_stage_time(sinks, "hammer", 1.0)
 
             threads = [threading.Thread(target=worker)
                        for _ in range(n_threads)]
@@ -115,23 +116,23 @@ class TestDebugLocks:
     sink mutation (validated through native.loader.env_int)."""
 
     def test_debug_locks_assertion_passes_on_locked_path(self, monkeypatch):
-        monkeypatch.setenv(profiler.DEBUG_LOCKS_ENV, "1")
+        monkeypatch.setenv(trace_lib.DEBUG_LOCKS_ENV, "1")
         with profiler.collect_stage_times() as sink:
             with profiler.stage("debug_locks_stage"):
                 pass
-            profiler._add_stage_time(profiler.current_sinks(),
-                                     "direct", 0.5)
+            trace_lib._add_stage_time(profiler.current_sinks(),
+                                      "direct", 0.5)
         assert "debug_locks_stage" in sink
         assert sink["direct"] == 0.5
 
     def test_debug_locks_off_by_default(self, monkeypatch):
-        monkeypatch.delenv(profiler.DEBUG_LOCKS_ENV, raising=False)
-        assert profiler._debug_locks() is False
+        monkeypatch.delenv(trace_lib.DEBUG_LOCKS_ENV, raising=False)
+        assert trace_lib._debug_locks() is False
 
     def test_debug_locks_env_is_validated(self, monkeypatch):
-        monkeypatch.setenv(profiler.DEBUG_LOCKS_ENV, "banana")
+        monkeypatch.setenv(trace_lib.DEBUG_LOCKS_ENV, "banana")
         with pytest.raises(ValueError, match="DEBUG_LOCKS"):
-            profiler._debug_locks()
-        monkeypatch.setenv(profiler.DEBUG_LOCKS_ENV, "7")
+            trace_lib._debug_locks()
+        monkeypatch.setenv(trace_lib.DEBUG_LOCKS_ENV, "7")
         with pytest.raises(ValueError, match=r"\[0, 1\]"):
-            profiler._debug_locks()
+            trace_lib._debug_locks()
