@@ -49,10 +49,11 @@ class NativeRequiredError(RuntimeError):
 
 
 # Worker-pool width for the native encode (pdp_pack_buckets,
-# pdp_rle_sort_range, pdp_rle_emit_range): 0 = auto (hardware
-# concurrency, capped at 16 in the C++), 1..64 forces the width. Output
-# is bit-identical at every width (disjoint buckets per worker); the knob
-# only trades host wall time — see README "Tuning knobs".
+# pdp_rle_prep, pdp_rle_sort_range, pdp_rle_emit_range): 0 = auto
+# (hardware concurrency, capped at 16 in the C++), 1..64 forces the
+# width. Output is bit-identical at every width (disjoint buckets or
+# disjoint write slots per worker); the knob only trades host wall time —
+# see README "Tuning knobs".
 ENCODE_THREADS_ENV = "PIPELINEDP_TPU_ENCODE_THREADS"
 
 
@@ -192,7 +193,7 @@ def load() -> Optional[ctypes.CDLL]:
 def load_row_packer() -> Optional[ctypes.CDLL]:
     """The row bucketing/packing library; None on failure."""
     lib = _load_lib("row_packer", "pdp_row_packer_abi_version",
-                    abi_version=7)
+                    abi_version=8)
     if lib is not None and not getattr(lib, "_pdp_typed", False):
         fn = lib.pdp_set_encode_threads
         fn.restype = None
@@ -216,7 +217,8 @@ def load_row_packer() -> Optional[ctypes.CDLL]:
             ctypes.c_int64,  # pid_span (for exact entry counting)
             ctypes.POINTER(ctypes.c_int64),  # n_entries out (or NULL)
             ctypes.POINTER(ctypes.c_int64),  # n_rows out
-            ctypes.POINTER(ctypes.c_int64),  # stats out [fail, max_idx]
+            # stats out [fail, max_idx, max_run, prep width]
+            ctypes.POINTER(ctypes.c_int64),
         ]
         fn = lib.pdp_rle_sort_range
         fn.restype = ctypes.c_int

@@ -21,6 +21,7 @@
 #include <cmath>
 #include <functional>
 #include <cstring>
+#include <memory>
 #include <thread>
 #include <vector>
 
@@ -33,8 +34,8 @@ constexpr uint32_t kHashMult = 2654435761u;
 // validated PIPELINEDP_TPU_ENCODE_THREADS value through before encode
 // calls. Output is bit-identical for every width: workers own disjoint
 // buckets (RunPool) or disjoint input ranges with precomputed write
-// offsets (pdp_pack_buckets), so the thread count only changes wall
-// time, never bytes.
+// offsets (pdp_pack_buckets, pdp_rle_prep), so the thread count only
+// changes wall time, never bytes.
 std::atomic<int> g_encode_threads{0};
 
 int64_t PoolWidth(int64_t auto_cap) {
@@ -209,7 +210,8 @@ int pdp_pack_buckets(const int32_t* pid, const int32_t* pk,
 //
 // Three-call API so the per-slab encode can overlap the previous slab's
 // async host->device transfer (ops/streaming.py drives it):
-//   pdp_rle_prep        one pass: bucket rows (pid-hash, same bucketing as
+//   pdp_rle_prep        count + scatter over contiguous row ranges on the
+//                       pool: bucket rows (pid-hash, same bucketing as
 //                       pdp_pack_buckets) into bucket-major SoA temps, and
 //                       (span permitting) exact per-bucket RLE entry
 //                       counts — so the wire format is known BEFORE any
@@ -228,8 +230,9 @@ int pdp_pack_buckets(const int32_t* pid, const int32_t* pk,
 //
 // Bit-planes are LSB-first: plane j, byte r>>3, bit r&7 = bit j of row r.
 // Packing works in 8-row register groups (one byte store per plane per 8
-// rows) — this box may have a single core, so the encoder is tuned for
-// single-thread throughput first, with an optional bucket-parallel pool.
+// rows). Every call runs on the PoolWidth worker pool (prep over row
+// ranges, sort and emit over buckets); the bytes never depend on the
+// width.
 // ---------------------------------------------------------------------------
 
 namespace {
@@ -244,6 +247,10 @@ constexpr int64_t kRunSplit = 65535;
 // (ops/streaming.py drives this).
 constexpr int64_t kMaxEntryCountSpan = int64_t{1} << 26;
 
+// Below this many rows prep stays on one thread whatever the pool width:
+// starting the workers costs more than the two passes they would split.
+constexpr int64_t kPrepParallelRows = int64_t{1} << 16;
+
 struct RleState {
   int64_t n = 0;
   int64_t k = 0;
@@ -251,10 +258,12 @@ struct RleState {
   bool low_grouped = false;  // prep pre-grouped rows by pid low byte
   std::vector<int64_t> bucket_start;  // [k+1]
   // Bucket-major SoA; after sort_range a bucket's slice is pid-sorted.
-  std::vector<uint32_t> tpid;
-  std::vector<int32_t> tpk;
-  std::vector<float> tval;
-  std::vector<int32_t> tvidx;
+  // Left uninitialised on allocation: prep's scatter writes every slot,
+  // so its workers take the first-touch page faults side by side.
+  std::unique_ptr<uint32_t[]> tpid;
+  std::unique_ptr<int32_t[]> tpk;
+  std::unique_ptr<float[]> tval;
+  std::unique_ptr<int32_t[]> tvidx;
   std::vector<char> sorted;  // per bucket
 };
 
@@ -370,8 +379,11 @@ void PackPlanes(const int32_t* col, int64_t m, int bits, int64_t cap8,
   }
 }
 
-void RunPool(int64_t k0, int64_t k1, const std::function<void(int64_t)>& fn) {
-  int64_t pool = PoolWidth(16);
+// fn(b) for every b in [k0, k1) on up to `pool` workers (default: the
+// PoolWidth pool), each taking the next index as it frees.
+void RunPool(int64_t k0, int64_t k1, const std::function<void(int64_t)>& fn,
+             int64_t pool = -1) {
+  if (pool < 0) pool = PoolWidth(16);
   if (pool > k1 - k0) pool = k1 - k0;
   if (pool <= 1) {
     for (int64_t b = k0; b < k1; ++b) fn(b);
@@ -395,10 +407,21 @@ void RunPool(int64_t k0, int64_t k1, const std::function<void(int64_t)>& fn) {
 
 extern "C" {
 
-// Prep: one counting pass + one scatter pass into bucket-major SoA temps.
-// The scatter ALSO groups rows by the pid low byte inside each bucket —
-// that is pass 0 of the stable LSD radix sort, so sort_range only runs
-// the remaining byte passes.
+// Prep: a counting pass and a scatter pass into bucket-major SoA temps,
+// both split into T contiguous row ranges on the pool (T = PoolWidth,
+// or 1 below kPrepParallelRows rows).
+//   1. Worker t counts its range per (bucket, pid low byte) into its own
+//      k x 256 table. The tables are prefix-summed in (bucket, low byte,
+//      worker) order, so worker t's rows for a cursor land right after
+//      worker t-1's: every row takes the slot a single-thread stable
+//      scatter gives it, and the temps are the same bytes at any T.
+//   2. Worker t scatters its range with its own cursors. The scatter
+//      groups rows by the pid low byte inside each bucket — that is pass
+//      0 of the stable LSD radix sort, so sort_range only runs the
+//      remaining byte passes.
+// The per-pid count table (below) is filled after the scatter, one
+// bucket per worker: a pid lands in exactly one bucket, so no two
+// workers touch the same entry.
 //
 // value_mode 1 with vidx == NULL computes the affine value index inline:
 // idx = rint((value - v_lo) / v_scale), verified bit-exact against the
@@ -407,7 +430,8 @@ extern "C" {
 // stats[1] returns the maximum index (for the bit-width of the planes);
 // stats[2] returns the max rows of any single pid when the count table
 // was built (ABI 7; -1 otherwise) — it bounds every pid segment in every
-// bucket, sizing the tile slack of the kernel's segment-local sort.
+// bucket, sizing the tile slack of the kernel's segment-local sort;
+// stats[3] returns T, the workers the two passes ran on (ABI 8).
 //
 // pid_span / n_entries: when n_entries is non-null and the shifted pid
 // span fits the count-table budget, n_entries[b] receives the EXACT
@@ -427,35 +451,123 @@ void* pdp_rle_prep(const int32_t* pid, const int32_t* pk, const float* value,
   if ((value_mode == 2 || value_mode == 3 || inline_vidx) && !value) {
     return nullptr;
   }
+  const int64_t n_threads = n < kPrepParallelRows ? 1 : PoolWidth(16);
+  const int64_t per = (n + n_threads - 1) / n_threads;
+  const int64_t table = k * 256;  // one worker's (bucket, low byte) cursors
+  const uint32_t ku = static_cast<uint32_t>(k);
   stats[0] = 0;
   stats[1] = 0;
   stats[2] = -1;
-  auto* st = new RleState();
+  stats[3] = n_threads;
+  std::unique_ptr<RleState> st(new RleState());
   st->n = n;
   st->k = k;
   st->value_mode = value_mode;
   st->low_grouped = true;
   st->bucket_start.assign(k + 1, 0);
   st->sorted.assign(k, 0);
-  // Pass 1: counts per (bucket, pid low byte) — the sub-cursor table that
-  // makes the scatter double as radix pass 0 — plus (when the span fits
-  // the budget) a per-pid count table for the exact RLE entry counts.
+  // Pass 1: each worker counts its rows per (bucket, pid low byte) — the
+  // sub-cursor table that makes the scatter double as radix pass 0.
+  std::vector<int64_t> sub(n_threads * table, 0);
+  RunPool(0, n_threads, [&](int64_t t) {
+    int64_t* local = &sub[t * table];
+    const int64_t hi = std::min(n, (t + 1) * per);
+    for (int64_t i = t * per; i < hi; ++i) {
+      const uint32_t spid = static_cast<uint32_t>(pid[i] - pid_lo);
+      local[(static_cast<int64_t>(BucketOf(pid[i] - pid_lo, ku)) << 8) |
+            (spid & 0xff)]++;
+    }
+  }, n_threads);
+  // Cursors in (bucket, low byte, worker) order.
+  {
+    int64_t acc = 0;
+    for (int64_t b = 0; b < k; ++b) {
+      st->bucket_start[b] = acc;
+      for (int64_t v = 0; v < 256; ++v) {
+        for (int64_t t = 0; t < n_threads; ++t) {
+          int64_t& cell = sub[t * table + ((b << 8) | v)];
+          const int64_t c = cell;
+          cell = acc;
+          acc += c;
+        }
+      }
+      n_rows[b] = acc - st->bucket_start[b];
+    }
+    st->bucket_start[k] = acc;
+  }
+  st->tpid.reset(new uint32_t[n]);
+  st->tpk.reset(new int32_t[n]);
+  if (value_mode == 2 || value_mode == 3) st->tval.reset(new float[n]);
+  if (value_mode == 1) st->tvidx.reset(new int32_t[n]);
+  // Pass 2: each worker scatters its rows through its own cursors, and
+  // keeps its own verification outcome and max index.
+  const float lo_f = static_cast<float>(v_lo);
+  const float scale_f = static_cast<float>(v_scale);
+  std::vector<char> failed(n_threads, 0);
+  std::vector<int64_t> max_idx(n_threads, 0);
+  RunPool(0, n_threads, [&](int64_t t) {
+    int64_t* cursor = &sub[t * table];
+    const int64_t hi = std::min(n, (t + 1) * per);
+    bool verify_failed = false;
+    int64_t local_max = 0;
+    for (int64_t i = t * per; i < hi; ++i) {
+      const uint32_t spid = static_cast<uint32_t>(pid[i] - pid_lo);
+      const uint32_t b = BucketOf(pid[i] - pid_lo, ku);
+      const int64_t slot = cursor[(static_cast<int64_t>(b) << 8) |
+                                  (spid & 0xff)]++;
+      st->tpid[slot] = spid;
+      st->tpk[slot] = pk[i];
+      if (value_mode == 2 || value_mode == 3) st->tval[slot] = value[i];
+      if (value_mode == 1) {
+        if (inline_vidx) {
+          if (verify_failed) break;  // state is discarded on failure
+          const float v = value[i];
+          // nearbyint: ties-to-even, matching the numpy reference's
+          // np.rint so native and fallback encoders emit bit-identical
+          // buffers.
+          const int64_t idx = static_cast<int64_t>(
+              std::nearbyint((static_cast<double>(v) - v_lo) / v_scale));
+          if (idx < 0 || idx >= (1 << 20)) {
+            verify_failed = true;
+            st->tvidx[slot] = 0;
+            continue;
+          }
+          const float rec = lo_f + static_cast<float>(idx) * scale_f;
+          uint32_t rb, vb;
+          std::memcpy(&rb, &rec, 4);
+          std::memcpy(&vb, &v, 4);
+          if (rb != vb) verify_failed = true;
+          if (idx > local_max) local_max = idx;
+          st->tvidx[slot] = static_cast<int32_t>(idx);
+        } else {
+          st->tvidx[slot] = vidx[i];
+        }
+      }
+    }
+    failed[t] = verify_failed;
+    max_idx[t] = local_max;
+  }, n_threads);
+  if (inline_vidx &&
+      std::find(failed.begin(), failed.end(), 1) != failed.end()) {
+    stats[0] = 1;
+    return nullptr;  // st is discarded
+  }
+  stats[1] = *std::max_element(max_idx.begin(), max_idx.end());
+  // Per-pid row counts (when the span fits the budget), one bucket per
+  // worker, for the exact RLE entry counts and the max run.
   const bool count_entries =
       n_entries != nullptr && pid_span >= 0 &&
       pid_span + 1 <= kMaxEntryCountSpan &&
       n <= static_cast<int64_t>(UINT32_MAX) / 2;
-  std::vector<uint32_t> pid_count;
-  if (count_entries) pid_count.assign(pid_span + 1, 0);
-  std::vector<int64_t> sub(k * 256, 0);
-  for (int64_t i = 0; i < n; ++i) {
-    const uint32_t spid = static_cast<uint32_t>(pid[i] - pid_lo);
-    sub[(static_cast<int64_t>(BucketOf(pid[i] - pid_lo,
-                                       static_cast<uint32_t>(k)))
-         << 8) | (spid & 0xff)]++;
-    if (count_entries) pid_count[spid]++;
-  }
   if (n_entries != nullptr) {
     if (count_entries) {
+      std::vector<uint32_t> pid_count(pid_span + 1, 0);
+      RunPool(0, k, [&](int64_t b) {
+        for (int64_t i = st->bucket_start[b]; i < st->bucket_start[b + 1];
+             ++i) {
+          pid_count[st->tpid[i]]++;
+        }
+      }, n_threads);
       for (int64_t b = 0; b < k; ++b) n_entries[b] = 0;
       int64_t max_run = 0;
       for (int64_t s = 0; s <= pid_span; ++s) {
@@ -464,8 +576,7 @@ void* pdp_rle_prep(const int32_t* pid, const int32_t* pk, const float* value,
           if (static_cast<int64_t>(c) > max_run) {
             max_run = static_cast<int64_t>(c);
           }
-          n_entries[BucketOf(static_cast<int32_t>(s),
-                             static_cast<uint32_t>(k))] +=
+          n_entries[BucketOf(static_cast<int32_t>(s), ku)] +=
               (c + kRunSplit - 1) / kRunSplit;
         }
       }
@@ -474,69 +585,7 @@ void* pdp_rle_prep(const int32_t* pid, const int32_t* pk, const float* value,
       n_entries[0] = -1;
     }
   }
-  {
-    int64_t acc = 0;
-    for (int64_t b = 0; b < k; ++b) {
-      st->bucket_start[b] = acc;
-      int64_t bucket_total = 0;
-      for (int v = 0; v < 256; ++v) {
-        const int64_t c = sub[(b << 8) | v];
-        sub[(b << 8) | v] = acc + bucket_total;
-        bucket_total += c;
-      }
-      n_rows[b] = bucket_total;
-      acc += bucket_total;
-    }
-    st->bucket_start[k] = acc;
-  }
-  st->tpid.resize(n);
-  st->tpk.resize(n);
-  if (value_mode == 2 || value_mode == 3) st->tval.resize(n);
-  if (value_mode == 1) st->tvidx.resize(n);
-  const float lo_f = static_cast<float>(v_lo);
-  const float scale_f = static_cast<float>(v_scale);
-  bool verify_failed = false;
-  int64_t max_idx = 0;
-  for (int64_t i = 0; i < n; ++i) {
-    const uint32_t spid = static_cast<uint32_t>(pid[i] - pid_lo);
-    const uint32_t b = BucketOf(pid[i] - pid_lo, static_cast<uint32_t>(k));
-    const int64_t slot = sub[(static_cast<int64_t>(b) << 8) |
-                             (spid & 0xff)]++;
-    st->tpid[slot] = spid;
-    st->tpk[slot] = pk[i];
-    if (value_mode == 2 || value_mode == 3) st->tval[slot] = value[i];
-    if (value_mode == 1) {
-      if (inline_vidx) {
-        if (verify_failed) break;  // state is discarded on failure
-        const float v = value[i];
-        // nearbyint: ties-to-even, matching the numpy reference's np.rint
-        // so native and fallback encoders emit bit-identical buffers.
-        const int64_t idx = static_cast<int64_t>(
-            std::nearbyint((static_cast<double>(v) - v_lo) / v_scale));
-        if (idx < 0 || idx >= (1 << 20)) {
-          verify_failed = true;
-          st->tvidx[slot] = 0;
-          continue;
-        }
-        const float rec = lo_f + static_cast<float>(idx) * scale_f;
-        uint32_t rb, vb;
-        std::memcpy(&rb, &rec, 4);
-        std::memcpy(&vb, &v, 4);
-        if (rb != vb) verify_failed = true;
-        if (idx > max_idx) max_idx = idx;
-        st->tvidx[slot] = static_cast<int32_t>(idx);
-      } else {
-        st->tvidx[slot] = vidx[i];
-      }
-    }
-  }
-  if (inline_vidx && verify_failed) {
-    stats[0] = 1;
-    delete st;
-    return nullptr;
-  }
-  stats[1] = max_idx;
-  return st;
+  return st.release();
 }
 
 int pdp_rle_sort_range(void* handle, int64_t b0, int64_t b1,
@@ -647,8 +696,8 @@ void pdp_rle_free(void* handle) { delete static_cast<RleState*>(handle); }
 
 // Encode worker-pool width: 0 restores auto (hardware_concurrency capped
 // at 16); values are clamped to [0, 64]. Applies to pdp_pack_buckets,
-// pdp_rle_sort_range and pdp_rle_emit_range. The callers' loader wires
-// PIPELINEDP_TPU_ENCODE_THREADS through here.
+// pdp_rle_prep, pdp_rle_sort_range and pdp_rle_emit_range. The callers'
+// loader wires PIPELINEDP_TPU_ENCODE_THREADS through here.
 void pdp_set_encode_threads(int n) {
   if (n < 0) n = 0;
   if (n > 64) n = 64;
@@ -659,6 +708,6 @@ int pdp_get_encode_threads() {
   return g_encode_threads.load(std::memory_order_relaxed);
 }
 
-int pdp_row_packer_abi_version() { return 7; }
+int pdp_row_packer_abi_version() { return 8; }
 
 }  // extern "C"

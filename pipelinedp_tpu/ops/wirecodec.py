@@ -568,7 +568,8 @@ class NativeRleEncoder:
         None). On verification failure returns None and sets
         out_status["inline_failed"] = True — callers re-plan. The
         returned encoder's plan carries the true bit width (from the
-        observed max index).
+        observed max index). out_status["threads"] receives the worker
+        count prep ran on.
 
         pid_span: max(pid) - pid_lo; when >= 0 and within the native
         count-table budget, prep also returns exact per-bucket RLE entry
@@ -591,8 +592,9 @@ class NativeRleEncoder:
         counts = np.zeros(k, dtype=np.int64)
         entries = np.zeros(k, dtype=np.int64)
         # stats: [0] inline verification failed, [1] max value index,
-        # [2] max rows of any single pid (ABI 7; -1 when uncounted).
-        stats = np.zeros(3, dtype=np.int64)
+        # [2] max rows of any single pid (ABI 7; -1 when uncounted),
+        # [3] the worker count prep ran on (ABI 8).
+        stats = np.zeros(4, dtype=np.int64)
         handle = lib.pdp_rle_prep(
             pid32.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
             pk32.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
@@ -605,6 +607,8 @@ class NativeRleEncoder:
             entries.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
             counts.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
             stats.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)))
+        if out_status is not None:
+            out_status["threads"] = int(stats[3])
         if not handle:
             if out_status is not None and use_inline and stats[0] == 1:
                 out_status["inline_failed"] = True
@@ -898,8 +902,9 @@ def make_encoder(pid: np.ndarray, pk, value, *, num_partitions: int, k: int,
     Each pass is a child span of the callers' ``dp/wire_prep``:
     ``dp/wire_prep/pid_range`` (pid min/max), ``.../value_plan`` (the
     sample plan, or the full plan on the re-plan and numpy paths),
-    ``.../native_scan`` (the native scatter / RLE-count pass) and
-    ``.../fallback_stats`` (numpy RLE entry counts).
+    ``.../native_scan`` (the native scatter / RLE-count pass; attribute
+    ``threads``, the worker count it ran on) and ``.../fallback_stats``
+    (numpy RLE entry counts).
 
     Returns (enc_or_None, EncodeInfo).
     """
@@ -937,11 +942,16 @@ def make_encoder(pid: np.ndarray, pk, value, *, num_partitions: int, k: int,
         with obs_trace.span("dp/wire_prep/fallback_stats"):
             return rle_entry_stats_numpy(pid, pid_lo, k, pid_span)
 
-    def native_scan(plan, vidx, **kw):
-        with obs_trace.span("dp/wire_prep/native_scan"):
-            return NativeRleEncoder.create(pid, pk, value, vidx,
-                                           pid_lo=pid_lo, k=k, plan=plan,
-                                           pid_span=pid_span, **kw)
+    def native_scan(plan, vidx, out_status=None, **kw):
+        status = {} if out_status is None else out_status
+        with obs_trace.span("dp/wire_prep/native_scan") as sp:
+            enc = NativeRleEncoder.create(pid, pk, value, vidx,
+                                          pid_lo=pid_lo, k=k, plan=plan,
+                                          pid_span=pid_span,
+                                          out_status=status, **kw)
+            if sp is not None:
+                sp.set_attribute("threads", status.get("threads", 0))
+        return enc
 
     def fallback_info():
         plan, vidx = full_plan()

@@ -1,21 +1,28 @@
 """Thread-parallel native encode (ISSUE 5 tentpole 1): determinism matrix.
 
 The native worker pool processes pid-disjoint buckets concurrently
-(row_packer.cc RunPool; width forced by PIPELINEDP_TPU_ENCODE_THREADS).
-The contract pinned here: emitted slabs are BYTE-IDENTICAL across thread
-counts {1, 4, hardware-auto} and equal to the numpy reference encoder,
-for the RLE, PID_PLANES, and raw-float value wire modes. CI runs this
-file core-pinned (taskset -c 0,1) as well, to catch any output that
-depends on the scheduler rather than the input.
+(row_packer.cc RunPool; width forced by PIPELINEDP_TPU_ENCODE_THREADS),
+and the prep scan splits its counting and scatter passes over contiguous
+row ranges. The contract pinned here: emitted slabs are BYTE-IDENTICAL
+across thread counts {1, 4, hardware-auto} and equal to the numpy
+reference encoder, for the RLE, PID_PLANES, and raw-float value wire
+modes, and so are prep's bucket row counts, RLE entry counts, max run
+and value plan. CI runs this file core-pinned (taskset -c 0,1) as well,
+to catch any output that depends on the scheduler rather than the input.
 """
 
 import numpy as np
 import pytest
 
 from pipelinedp_tpu.native import loader
+from pipelinedp_tpu.obs import trace as trace_lib
 from pipelinedp_tpu.ops import streaming, wirecodec
 
 THREAD_MATRIX = ("1", "4", "")  # "" = auto (hardware concurrency)
+# Prep also runs with more workers than cores, so a lost write or a
+# misordered cursor shows however the scheduler interleaves them.
+PREP_WIDTHS = THREAD_MATRIX + ("64",)
+PREP_PARALLEL_ROWS = 1 << 16  # kPrepParallelRows in row_packer.cc
 
 
 def _require_native():
@@ -27,7 +34,40 @@ def _require_native():
 
 def _dataset(kind, n=120_000, seed=0):
     rng = np.random.default_rng(seed)
-    if kind == "rle_planes_values":
+    if kind == "below_prep_threshold":
+        n = PREP_PARALLEL_ROWS - 1_000
+        pid = rng.integers(0, n // 12, n).astype(np.int32)
+        value = rng.integers(1, 6, n).astype(np.float32)
+    elif kind == "above_prep_threshold":
+        n = 4 * PREP_PARALLEL_ROWS + 77  # uneven worker ranges
+        pid = rng.integers(0, n // 12, n).astype(np.int32)
+        value = rng.integers(1, 6, n).astype(np.float32)
+    elif kind == "run_split":
+        # One pid holds 70,000 rows, spread over every worker's range:
+        # its run splits at 65,535 and sets max_run.
+        pid = rng.integers(0, 9_000, n).astype(np.int32)
+        pid[rng.choice(n, 70_000, replace=False)] = 4_321
+        value = rng.integers(1, 6, n).astype(np.float32)
+    elif kind == "span_above_count_budget":
+        # Shifted pid span above 2^26: prep builds no count table, so
+        # entry_counts is None and max_run -1.
+        pid = rng.integers(0, 2_000, n).astype(np.int32)
+        pid[rng.integers(0, n, 50)] = (1 << 26) + 12_345
+        value = rng.integers(1, 6, n).astype(np.float32)
+    elif kind == "inline_fails_rescale":
+        # The 64k-row sample is whole stars (scale 1 passes the gate);
+        # half stars in the last worker's range fail inline verification
+        # there, and the full re-plan picks scale 0.5.
+        pid = rng.integers(0, n // 12, n).astype(np.int32)
+        value = rng.integers(1, 6, n).astype(np.float32)
+        value[n - 5_000:] += 0.5
+    elif kind == "inline_fails_range":
+        # One index past 2^20 in the third worker's range: no scale
+        # passes the full verification, so the re-plan ships raw floats.
+        pid = rng.integers(0, n // 12, n).astype(np.int32)
+        value = rng.integers(1, 6, n).astype(np.float32)
+        value[n * 5 // 8] = 3.0e6
+    elif kind == "rle_planes_values":
         # Repetitive ids (~12 rows/user) -> PID_RLE; integer star
         # ratings -> affine-integer value planes.
         pid = rng.integers(0, n // 12, n).astype(np.int32)
@@ -121,6 +161,65 @@ class TestDeterminismMatrix:
         for bufs, counts in outs[1:]:
             np.testing.assert_array_equal(outs[0][1], counts)
             np.testing.assert_array_equal(outs[0][0], bufs)
+
+
+class TestPrepAcrossWidths:
+    """pdp_rle_prep's outputs (bucket row counts, RLE entry counts, max
+    run, the inline value plan) and the slabs emitted from them are the
+    same at every pool width and equal to the numpy oracle, on both
+    sides of the single-thread threshold and on the re-plan path."""
+
+    REPLANNED = ("inline_fails_rescale", "inline_fails_range")
+
+    @pytest.mark.parametrize("kind", [
+        "below_prep_threshold", "above_prep_threshold", "run_split",
+        "span_above_count_budget", *REPLANNED])
+    def test_prep_identical_across_widths_and_numpy(self, kind,
+                                                    monkeypatch):
+        _require_native()
+        pid, pk, value = _dataset(kind)
+        k = 6
+        outs = {}
+        tracer = trace_lib.install(trace_lib.Tracer())
+        try:
+            for threads in PREP_WIDTHS:
+                tracer.reset()
+                outs[threads] = _encode_native(pid, pk, value, k,
+                                               monkeypatch, threads)
+                scans = [sp.attrs["threads"] for sp in tracer.spans()
+                         if sp.name == "dp/wire_prep/native_scan"]
+                # A failed inline verification scans twice (re-plan).
+                assert len(scans) == (2 if kind in self.REPLANNED else 1)
+                if threads in ("4", "64"):
+                    wide = len(pid) >= PREP_PARALLEL_ROWS
+                    want = int(threads) if wide else 1
+                    assert scans == [want] * len(scans)
+        finally:
+            trace_lib.shutdown()
+        info = outs["1"][4]
+        plan, _ = wirecodec.plan_and_index(value)
+        entries, max_run = wirecodec.rle_entry_stats_numpy(
+            pid, info.pid_lo, k, info.pid_span)
+        ref_slab, ref_counts, _, ref_fmt = wirecodec.encode_buckets_numpy(
+            pid, pk, value, pid_lo=info.pid_lo, k=k,
+            bytes_pid=info.bytes_pid, bits_pk=info.bits_pk, plan=plan,
+            pid_mode=info.pid_mode, bits_pid=info.bits_pid)
+        if kind == "span_above_count_budget":
+            assert entries is None
+        if kind == "run_split":
+            assert max_run > 65_535
+        for threads, (slab, counts, _, fmt, info) in outs.items():
+            width = threads or "auto"
+            assert info.plan == plan, width
+            np.testing.assert_array_equal(counts, ref_counts, err_msg=width)
+            if entries is None:
+                assert info.entry_counts is None and info.max_run == -1
+            else:
+                np.testing.assert_array_equal(info.entry_counts, entries,
+                                              err_msg=width)
+                assert info.max_run == max_run, width
+            assert fmt == ref_fmt, width
+            np.testing.assert_array_equal(slab, ref_slab, err_msg=width)
 
 
 class TestEncodeThreadsKnob:

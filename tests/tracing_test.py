@@ -13,6 +13,7 @@ import pytest
 
 import pipelinedp_tpu as pdp
 from pipelinedp_tpu import profiler, runtime
+from pipelinedp_tpu.native import loader
 from pipelinedp_tpu.obs import metrics as metrics_lib
 from pipelinedp_tpu.obs import trace as trace_lib
 from pipelinedp_tpu.ops import streaming, wirecodec
@@ -162,6 +163,21 @@ class TestChildSpans:
         stages = self._stages(checkpoint_policy=policy,
                               watchdog_timeout_s=60.0)
         assert {"dp/checkpoint", "dp/slab_sync"} <= set(stages)
+
+    @pytest.mark.parametrize("n_rows,want", [(70_000, 4), (60_000, 1)])
+    def test_native_scan_records_its_width(self, tracer, monkeypatch,
+                                           n_rows, want):
+        # The prep scan splits over the pool from 2^16 rows on; below
+        # that it stays on one thread whatever the forced width.
+        if wirecodec._load_packer() is None:
+            pytest.skip("native packer unavailable")
+        monkeypatch.setenv(loader.ENCODE_THREADS_ENV, "4")
+        _aggregate(_data(n=n_rows))
+        (scan,) = [sp for sp in tracer.spans()
+                   if sp.name == "dp/wire_prep/native_scan"]
+        assert scan.attrs["threads"] == want
+        parent = {sp.span_id: sp.name for sp in tracer.spans()}
+        assert parent[scan.parent_id] == "dp/wire_prep"
 
 
 def _counter_deltas(fn):
