@@ -267,6 +267,17 @@ class _PreparedQuery:
     duration_s: Optional[float] = None
 
 
+@jax.jit
+def _unstack_lanes(accs_b) -> tuple:
+    """The B lanes of a batched [B, num_partitions] fold as B
+    PartitionAccumulators, in one program. Slicing lane by lane and field
+    by field dispatches B x 5 programs behind the batched replay, and on
+    a TPU those fill the device's queue: the host then waits for the
+    replay to drain before it can hand any lane to an epilogue."""
+    return tuple(columnar.PartitionAccumulators(*(a[b] for a in accs_b))
+                 for b in range(accs_b[0].shape[0]))
+
+
 class DatasetSession:
     """A resident dataset serving many DP queries (module docstring).
 
@@ -1478,10 +1489,11 @@ class DatasetSession:
             prepared: List[_PreparedQuery] = []
             results: List[Optional[dict]] = [None] * len(configs)
             try:
-                for i, cfg in enumerate(configs):
-                    prepared.append(
-                        self._prepare_query(i, cfg, secure_host_noise))
-                plan, cached_results = self._plan_batch(prepared, width)
+                with obs_trace.span("dp/serve/plan", n_configs=len(configs)):
+                    for i, cfg in enumerate(configs):
+                        prepared.append(
+                            self._prepare_query(i, cfg, secure_host_noise))
+                    plan, cached_results = self._plan_batch(prepared, width)
                 self._execute_plan(plan, prepared, cached_results,
                                    results, shn, batch_span, t_b0)
             except BaseException as exc:
@@ -1517,30 +1529,31 @@ class DatasetSession:
         time."""
         outcome_on_failure = (self._failure_outcome(exc)
                               if exc is not None else "refunded")
-        for p in prepared:
-            cfg = configs[p.index]
-            token = finalize_ops.release_token(
-                p.engine._key_stream.fingerprint(), p.key_counter)
-            cols = results[p.index]
-            released = cols is not None or (
-                p.state is not None
-                and p.state.release_journal.has(token))
-            kept = dropped = -1
-            if cols is not None:
-                keep = np.asarray(cols["keep_mask"])
-                kept = int(keep.sum())
-                dropped = int(keep.size) - kept
-            self._audit.record(
-                session=self._name, tenant=cfg.tenant, token=str(token),
-                outcome="released" if released else outcome_on_failure,
-                mechanisms=[str(m) for m in cfg.metrics],
-                noise_kind=getattr(cfg.noise_kind, "value",
-                                   str(cfg.noise_kind)),
-                epsilon=float(cfg.epsilon), delta=float(cfg.delta),
-                partitions_kept=kept, partitions_dropped=dropped,
-                duration_s=(p.duration_s if p.duration_s is not None
-                            else duration_s),
-                seed=cfg.seed, trace_id=qid)
+        with obs_trace.span("dp/audit_append", n_records=len(prepared)):
+            for p in prepared:
+                cfg = configs[p.index]
+                token = finalize_ops.release_token(
+                    p.engine._key_stream.fingerprint(), p.key_counter)
+                cols = results[p.index]
+                released = cols is not None or (
+                    p.state is not None
+                    and p.state.release_journal.has(token))
+                kept = dropped = -1
+                if cols is not None:
+                    keep = np.asarray(cols["keep_mask"])
+                    kept = int(keep.sum())
+                    dropped = int(keep.size) - kept
+                self._audit.record(
+                    session=self._name, tenant=cfg.tenant, token=str(token),
+                    outcome="released" if released else outcome_on_failure,
+                    mechanisms=[str(m) for m in cfg.metrics],
+                    noise_kind=getattr(cfg.noise_kind, "value",
+                                       str(cfg.noise_kind)),
+                    epsilon=float(cfg.epsilon), delta=float(cfg.delta),
+                    partitions_kept=kept, partitions_dropped=dropped,
+                    duration_s=(p.duration_s if p.duration_s is not None
+                                else duration_s),
+                    seed=cfg.seed, trace_id=qid)
 
     # -- the query plane (serving/planner.py) ----------------------------
 
@@ -1645,18 +1658,19 @@ class DatasetSession:
             return streaming.replay_resident_wire_batched(
                 keys, self._wire, **kwargs)
 
-    def _lane_accs(self, accs_b, b: int):
-        """Lane b's [num_partitions] accumulators out of the batched
-        [B, num_partitions] fold; on a mesh the slice is re-laid-out to
-        the partition sharding the sequential replay produces."""
-        accs = columnar.PartitionAccumulators(*(a[b] for a in accs_b))
+    def _lane_accs(self, accs_b) -> List[columnar.PartitionAccumulators]:
+        """Each lane's [num_partitions] accumulators out of the batched
+        [B, num_partitions] fold, sliced in one program; on a mesh each
+        slice is re-laid-out to the partition sharding the sequential
+        replay produces."""
+        lanes = _unstack_lanes(accs_b)
         if self._mesh is not None:
             from pipelinedp_tpu.parallel import sharded
             part = jax.sharding.NamedSharding(
                 self._mesh, sharded._part_spec(self._mesh))
-            accs = columnar.PartitionAccumulators(
-                *(jax.device_put(a, part) for a in accs))
-        return accs
+            lanes = [columnar.PartitionAccumulators(
+                *(jax.device_put(a, part) for a in accs)) for accs in lanes]
+        return list(lanes)
 
     def _execute_plan(self, plan, prepared: List[_PreparedQuery],
                       cached_results: Dict[int, Any],
@@ -1705,7 +1719,9 @@ class DatasetSession:
             # Cross-thread telemetry handoff: the worker joins the batch
             # caller's stage-time sinks and span tree.
             with profiler.adopt_sinks(parent_sinks), \
-                    obs_trace.attach(batch_span):
+                    obs_trace.attach(batch_span), \
+                    obs_trace.span("dp/serve/epilogue", lane=p.index,
+                                   l0=p.params.max_partitions_contributed):
                 p.accountant.compute_budgets()
                 # At-most-once: the release token commits before any
                 # noise draw, through this config's (tenant) journal.
@@ -1747,19 +1763,26 @@ class DatasetSession:
                 lanes = [by_index[lane.owner] for lane in group.lanes]
                 mark = len(all_futs)
                 t_r0 = time.perf_counter()
-                accs_b = self._replay_group_batched(group, lanes)
+                with obs_trace.span("dp/serve/replay", width=len(lanes),
+                                    n_chunks=self._wire.n_chunks):
+                    accs_b = self._replay_group_batched(group, lanes)
                 replay_s += time.perf_counter() - t_r0
+                with obs_trace.span("dp/serve/lane_slice",
+                                    width=len(lanes)):
+                    lane_accs = self._lane_accs(accs_b)
+                    for b, lane in enumerate(group.lanes):
+                        if group.flags_exact[b]:
+                            # Populate the bound cache FROM the batch:
+                            # this launch computed exactly the owner's
+                            # columns (union == own flags), so the lane's
+                            # result is what a solo replay would have
+                            # cached.
+                            self._cache_insert(
+                                by_index[lane.owner].bound_key,
+                                lane_accs[b])
                 for b, lane in enumerate(group.lanes):
-                    accs = self._lane_accs(accs_b, b)
-                    owner = by_index[lane.owner]
-                    if group.flags_exact[b]:
-                        # Populate the bound cache FROM the batch: this
-                        # launch computed exactly the owner's columns
-                        # (union == own flags), so the lane's result is
-                        # what a solo replay would have cached.
-                        self._cache_insert(owner.bound_key, accs)
                     for idx in lane.indexes:
-                        submit(by_index[idx], accs)
+                        submit(by_index[idx], lane_accs[b])
                 group_futs.append(all_futs[mark:])
             for f in all_futs:
                 f.result()

@@ -5,6 +5,7 @@ counters; the compile listener; released bits unchanged by tracing."""
 import glob
 import json
 import os
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -178,6 +179,64 @@ class TestChildSpans:
         assert scan.attrs["threads"] == want
         parent = {sp.span_id: sp.name for sp in tracer.spans()}
         assert parent[scan.parent_id] == "dp/wire_prep"
+
+
+class TestServeSpans:
+    """The query plane's spans under ``serving/query_batch``: planning,
+    the batched replay, the lanes' slicing, and each config's epilogue
+    on the executor thread."""
+
+    L0 = (1, 2, 4)
+
+    @pytest.fixture
+    def spans(self, tracer):
+        from pipelinedp_tpu import serving
+        params = _params()
+        configs = [serving.QueryConfig(
+            metrics=params.metrics, epsilon=1.0, delta=1e-6,
+            max_partitions_contributed=l0, max_contributions_per_partition=1,
+            min_value=1.0, max_value=5.0, seed=20 + l0) for l0 in self.L0]
+        with serving.DatasetSession(_data(), n_chunks=4,
+                                    name="serve-spans") as session:
+            with profiler.collect_stage_times() as stages:
+                session.query_batch(configs)
+        spans = tracer.spans()
+        (batch,) = [sp for sp in spans if sp.name == "serving/query_batch"]
+        return spans, batch, dict(stages), threading.get_ident()
+
+    @staticmethod
+    def _named(spans, name):
+        return [sp for sp in spans if sp.name == name]
+
+    @pytest.mark.parametrize("name,count", [
+        ("dp/serve/plan", 1), ("dp/serve/replay", 1),
+        ("dp/serve/lane_slice", 1), ("dp/serve/epilogue", len(L0))])
+    def test_each_span_is_a_child_of_query_batch(self, spans, name, count):
+        spans, batch, stages, _ = spans
+        found = self._named(spans, name)
+        assert len(found) == count
+        assert all(sp.parent_id == batch.span_id for sp in found)
+        assert name in stages
+
+    def test_replay_and_lane_slice_record_their_width(self, spans):
+        spans, _, _, _ = spans
+        (replay,) = self._named(spans, "dp/serve/replay")
+        assert replay.attrs == {"width": len(self.L0), "n_chunks": 4}
+        (lanes,) = self._named(spans, "dp/serve/lane_slice")
+        assert lanes.attrs == {"width": len(self.L0)}
+        (inner,) = self._named(spans, "serving/replay_batched")
+        assert inner.parent_id == replay.span_id
+
+    def test_epilogue_runs_on_the_executor_with_lane_and_l0(self, spans):
+        spans, _, _, main = spans
+        epilogues = self._named(spans, "dp/serve/epilogue")
+        assert sorted((sp.attrs["lane"], sp.attrs["l0"])
+                      for sp in epilogues) == list(enumerate(self.L0))
+        assert all(sp.thread_id != main for sp in epilogues)
+        ids = {sp.span_id for sp in epilogues}
+        finalizes = self._named(spans, "dp/finalize")
+        assert len(finalizes) == len(self.L0)
+        assert all(sp.parent_id in ids for sp in finalizes)
 
 
 def _counter_deltas(fn):
