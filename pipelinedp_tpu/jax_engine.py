@@ -1314,7 +1314,7 @@ class JaxDPEngine:
         t_fin0 = time.perf_counter()
         with obs_trace.span("dp/finalize",
                             secure_host_noise=self._secure_host_noise,
-                            n_metrics=len(compound.combiners)):
+                            n_metrics=len(compound.combiners)) as fin_span:
             if self._secure_host_noise:
                 # One batched device→host transfer of every device-resident
                 # input; selection, noise and metric math then run in
@@ -1324,8 +1324,11 @@ class JaxDPEngine:
                     # dplint: disable=DPL007 — secure-host-noise path: this transfer IS the mechanism boundary; host_epilogue adds float64 noise_core noise before anything is released
                     host_accs, host_vec = jax.device_get(
                         (accs, vector_sums))
-                metric_cols, keep = finalize_ops.host_epilogue(
-                    plan, scalars, host_accs, host_vec)
+                with noise_core.track_draw_threads() as noise_threads:
+                    metric_cols, keep = finalize_ops.host_epilogue(
+                        plan, scalars, host_accs, host_vec)
+                if fin_span is not None:
+                    fin_span.set_attribute("noise_threads", noise_threads[0])
             else:
                 operands = finalize_ops.device_operands(
                     plan, scalars, accs, vector_sums, k_select, k_noise)

@@ -8,7 +8,7 @@ available, the numpy granularity-snapping fallback in noise_core stays in
 place (distributionally equivalent; without the bit-exact discrete
 construction).
 
-Python <-> C++ boundary: ctypes over a 3-function C ABI (the environment
+Python <-> C++ boundary: ctypes over a 5-function C ABI (the environment
 has no pybind11 — see the repo build notes). The samplers return *integer*
 noise in granularity units; scaling by the power-of-two granularity happens
 here, which is exact in float64.
@@ -173,8 +173,10 @@ def _try_load(so: str, abi_symbol: str,
 
 def load() -> Optional[ctypes.CDLL]:
     """The secure-noise library, building it if needed; None on failure."""
-    lib = _load_lib("secure_noise", "pdp_noise_abi_version", abi_version=2)
+    lib = _load_lib("secure_noise", "pdp_noise_abi_version", abi_version=3)
     if lib is not None and not getattr(lib, "_pdp_typed", False):
+        lib.pdp_noise_threads_for.restype = ctypes.c_int
+        lib.pdp_noise_threads_for.argtypes = [ctypes.c_int64]
         for name in ("pdp_sample_discrete_laplace",
                      "pdp_sample_discrete_gaussian"):
             fn = getattr(lib, name)
@@ -296,15 +298,20 @@ def install() -> bool:
         return False
     from pipelinedp_tpu import noise_core
 
+    def note_draw(n: int) -> None:
+        noise_core.note_draw(lib.pdp_noise_threads_for(n))
+
     def native_laplace(scale: float, size=None):
         g = noise_core.laplace_granularity(scale)
         ints = _sample(lib.pdp_sample_discrete_laplace, scale / g, size)
+        note_draw(ints.size)
         noise = ints.astype(np.float64) * g
         return float(noise[0]) if size is None else noise.reshape(size)
 
     def native_gaussian(stddev: float, size=None):
         g = noise_core.gaussian_granularity(stddev)
         ints = _sample(lib.pdp_sample_discrete_gaussian, stddev / g, size)
+        note_draw(ints.size)
         noise = ints.astype(np.float64) * g
         return float(noise[0]) if size is None else noise.reshape(size)
 
@@ -315,6 +322,7 @@ def install() -> bool:
             out.ctypes.data_as(ctypes.POINTER(ctypes.c_double)), n)
         if rc != 0:
             raise ValueError("native uniform sampler failed")
+        note_draw(n)
         return float(out[0]) if size is None else out[:n].reshape(size)
 
     noise_core.sample_laplace = native_laplace

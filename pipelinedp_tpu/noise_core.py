@@ -137,11 +137,41 @@ def laplace_diversity(eps: float, l1_sensitivity: float) -> float:
 # because backends may draw noise from worker threads.
 # ---------------------------------------------------------------------------
 
+import contextlib as _contextlib
 import logging as _logging
 import threading as _threading
 
+from pipelinedp_tpu import profiler as _profiler
+
 _rng = np.random.default_rng()
 _rng_lock = _threading.Lock()
+
+# Profiler counters (OBSERVABILITY.md): every draw through the hooks below,
+# and the draws the native sampler split across more than one host thread.
+DRAWS_EVENT = "noise/draws"
+SPLIT_DRAWS_EVENT = "noise/split_draws"
+_draw_peak = _threading.local()
+
+
+def note_draw(threads: int) -> None:
+    """Counts one draw that ran on ``threads`` host threads."""
+    _profiler.count_event(DRAWS_EVENT)
+    if threads > 1:
+        _profiler.count_event(SPLIT_DRAWS_EVENT)
+    peak = getattr(_draw_peak, "value", None)
+    if peak is not None and threads > peak[0]:
+        peak[0] = threads
+
+
+@_contextlib.contextmanager
+def track_draw_threads():
+    """Yields a one-item list that ends as the most host threads any draw
+    on this thread used inside the block (0 when nothing was drawn)."""
+    peak = _draw_peak.value = [0]
+    try:
+        yield peak
+    finally:
+        _draw_peak.value = None
 
 
 def seed_fallback_rng(seed: Optional[int]) -> None:
@@ -160,6 +190,7 @@ def _fallback_laplace(scale: float, size=None):
     g = laplace_granularity(scale)
     with _rng_lock:
         raw = _rng.laplace(0.0, scale, size)
+    note_draw(1)
     return round_to_granularity(raw, g)
 
 
@@ -167,10 +198,12 @@ def _fallback_gaussian(stddev: float, size=None):
     g = gaussian_granularity(stddev)
     with _rng_lock:
         raw = _rng.normal(0.0, stddev, size)
+    note_draw(1)
     return round_to_granularity(raw, g)
 
 
 def _fallback_uniform(size=None):
+    note_draw(1)
     with _rng_lock:
         return _rng.random() if size is None else _rng.random(size)
 

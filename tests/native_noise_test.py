@@ -8,6 +8,13 @@ granularity multiples, and reject bad parameters. Role parity:
 (the reference verifies C++ noise is wired; here the C++ lives in-repo).
 """
 
+import ctypes
+import os
+import select
+import signal
+import threading
+import warnings
+
 import numpy as np
 import pytest
 from scipy import stats
@@ -64,7 +71,6 @@ class TestNativeSamplers:
         assert not np.array_equal(a, b)
 
     def test_invalid_parameters_rejected(self, lib):
-        import ctypes
         out = np.empty(1, dtype=np.int64)
         ptr = out.ctypes.data_as(ctypes.POINTER(ctypes.c_int64))
         assert lib.pdp_sample_discrete_laplace(ptr, 1, 0.0) != 0
@@ -147,3 +153,136 @@ class TestNativeSamplers:
         g = noise_core.laplace_granularity(scale)
         np.testing.assert_allclose(np.round(counts / g) * g, counts,
                                    atol=1e-9)
+
+
+# secure_noise.cc's kBlock: draws longer than one block split across threads.
+BLOCK = 4096
+SIZES = (1, BLOCK - 1, BLOCK, 17_770, 59_047, 200_000)
+KINDS = ("laplace", "gaussian", "uniform")
+
+
+def _width():
+    return min(len(os.sched_getaffinity(0)), 16)
+
+
+def _native_draw(lib, kind, n):
+    """(values, target distribution) of one native draw of n into a
+    zero-filled buffer, so a slice no worker wrote stays all zeros."""
+    if kind == "uniform":
+        out = np.zeros(n, dtype=np.float64)
+        ptr = out.ctypes.data_as(ctypes.POINTER(ctypes.c_double))
+        assert lib.pdp_sample_uniform_double(ptr, n) == 0
+        return out, stats.uniform()
+    out = np.zeros(n, dtype=np.int64)
+    ptr = out.ctypes.data_as(ctypes.POINTER(ctypes.c_int64))
+    if kind == "laplace":
+        scale = 3.0
+        g = noise_core.laplace_granularity(scale)
+        assert lib.pdp_sample_discrete_laplace(ptr, n, scale / g) == 0
+        return out * g, stats.laplace(scale=scale)
+    stddev = 7.5
+    g = noise_core.gaussian_granularity(stddev)
+    assert lib.pdp_sample_discrete_gaussian(ptr, n, stddev / g) == 0
+    return out * g, stats.norm(scale=stddev)
+
+
+def _check_distribution(values, dist):
+    # KS and the standard deviation at ~7 standard errors: the test makes
+    # ~150 such checks per run, and a block of zeros fails both.
+    _, p = stats.kstest(values, dist.cdf)
+    assert p > 1e-6
+    assert abs(values.std() / dist.std() - 1) < 8 / np.sqrt(len(values))
+
+
+class TestBlockSplit:
+    """Draws split into blocks over host threads (secure_noise.cc's
+    ParallelFill): every block is a full draw of the distribution, from
+    entropy no other block or call shares."""
+
+    @pytest.mark.parametrize("n", SIZES)
+    def test_threads_for(self, lib, n):
+        threads = lib.pdp_noise_threads_for(n)
+        assert 1 <= threads <= _width()
+        assert threads == min(-(-n // BLOCK), _width())
+        if n <= BLOCK:
+            assert threads == 1
+
+    def test_one_value_past_a_block_splits(self, lib):
+        assert lib.pdp_noise_threads_for(BLOCK + 1) == min(2, _width())
+
+    @pytest.mark.parametrize("kind", KINDS)
+    @pytest.mark.parametrize("n", SIZES)
+    def test_whole_draw_and_each_block_match_the_distribution(
+            self, lib, kind, n):
+        values, dist = _native_draw(lib, kind, n)
+        _check_distribution(values, dist)
+        for s in range(0, n, BLOCK):
+            _check_distribution(values[s:s + BLOCK], dist)
+
+    @pytest.mark.parametrize("kind", KINDS)
+    @pytest.mark.parametrize("n", SIZES)
+    def test_blocks_and_calls_never_repeat(self, lib, kind, n):
+        first, _ = _native_draw(lib, kind, n)
+        second, _ = _native_draw(lib, kind, n)
+        assert not np.array_equal(first, second)
+        full = [first[s:s + BLOCK].tobytes()
+                for s in range(0, n - BLOCK + 1, BLOCK)]
+        assert len(set(full)) == len(full)
+
+    def test_concurrent_callers_share_the_pool(self, lib):
+        # More calling threads than CPUs, each making split draws: the
+        # pool serves one draw at a time and every call must finish with
+        # every block written.
+        n, rounds = 17_770, 5
+        results, errors = [], []
+
+        def caller():
+            try:
+                for _ in range(rounds):
+                    values, dist = _native_draw(lib, "gaussian", n)
+                    for s in range(0, n, BLOCK):
+                        assert np.count_nonzero(values[s:s + BLOCK]) > 0
+                    results.append(values.tobytes())
+            except AssertionError as e:
+                errors.append(e)
+
+        threads = [threading.Thread(target=caller)
+                   for _ in range(2 * _width() + 1)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+        assert not errors
+        assert len(set(results)) == len(threads) * rounds
+
+    def test_forked_child_draws_fresh_entropy(self, lib):
+        # The child inherits the forking thread's buffered entropy and
+        # none of the pool's threads: its draws must neither replay the
+        # parent's next values nor wait on a pool that is gone.
+        _native_draw(lib, "laplace", 8)  # buffers entropy on this thread
+        _native_draw(lib, "laplace", 59_047)  # starts the pool
+        read_fd, write_fd = os.pipe()
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore")  # fork under JAX's threads
+            pid = os.fork()
+        if pid == 0:
+            try:
+                small, _ = _native_draw(lib, "laplace", 8)
+                big, _ = _native_draw(lib, "laplace", 59_047)
+                os.write(write_fd, small.tobytes() + big[:8].tobytes())
+            finally:
+                os._exit(0)
+        os.close(write_fd)
+        small, _ = _native_draw(lib, "laplace", 8)
+        big, _ = _native_draw(lib, "laplace", 59_047)
+        ready, _, _ = select.select([read_fd], [], [], 60)
+        if not ready:
+            os.kill(pid, signal.SIGKILL)
+        os.waitpid(pid, 0)
+        assert ready, "the forked child's draws did not finish"
+        data = os.read(read_fd, 128)
+        os.close(read_fd)
+        assert len(data) == 128
+        assert not np.array_equal(np.frombuffer(data[:64]), small)
+        assert not np.array_equal(np.frombuffer(data[64:]), big[:8])

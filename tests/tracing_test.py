@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 import pipelinedp_tpu as pdp
-from pipelinedp_tpu import profiler, runtime
+from pipelinedp_tpu import noise_core, profiler, runtime
 from pipelinedp_tpu.native import loader
 from pipelinedp_tpu.obs import metrics as metrics_lib
 from pipelinedp_tpu.obs import trace as trace_lib
@@ -325,6 +325,58 @@ class TestCompileListener:
         prom = metrics_lib.default_registry().to_prometheus()
         assert ('pipelinedp_tpu_compile_seconds_count'
                 '{program="jit(tracing_test_fresh_program)"} 1') in prom
+
+
+class TestNoiseCounters:
+    """``noise/draws`` counts every sampler draw, ``noise/split_draws``
+    the draws that ran on more than one host thread, and ``dp/finalize``
+    records the most threads any of its draws used."""
+
+    EVENTS = (noise_core.DRAWS_EVENT, noise_core.SPLIT_DRAWS_EVENT)
+
+    @pytest.fixture
+    def native(self, monkeypatch):
+        # Restore the hooks afterwards: other tests route draws through
+        # the seeded fallback.
+        for hook in ("sample_laplace", "sample_gaussian", "sample_uniform"):
+            monkeypatch.setattr(noise_core, hook, getattr(noise_core, hook))
+        if not loader.install():
+            pytest.skip("native library unavailable (no compiler)")
+        if len(os.sched_getaffinity(0)) < 2:
+            pytest.skip("one CPU: no draw can split")
+
+    def _deltas(self, fn):
+        before = [profiler.event_count(e) for e in self.EVENTS]
+        fn()
+        return [profiler.event_count(e) - b
+                for e, b in zip(self.EVENTS, before)]
+
+    def test_secure_finalize_of_59047_partitions_splits(self, native,
+                                                        tracer):
+        n = 59_047
+        rng = np.random.default_rng(5)
+        data = pdp.ColumnarData(
+            pid=np.arange(2 * n), pk=np.tile(np.arange(n), 2),
+            value=rng.integers(1, 6, 2 * n).astype(np.float32))
+
+        def release():
+            accountant = pdp.NaiveBudgetAccountant(1.0, 1e-6)
+            engine = pdp.JaxDPEngine(accountant, seed=7)
+            result = engine.aggregate(data, _params())
+            accountant.compute_budgets()
+            assert len(result.to_columns()["count"]) == n
+
+        draws, split = self._deltas(release)
+        # The selection uniforms and the three metrics' noise, each one
+        # draw of n values.
+        assert draws >= 4 and split >= 4
+        (fin,) = [sp for sp in tracer.spans() if sp.name == "dp/finalize"]
+        assert fin.attrs["secure_host_noise"] is True
+        assert fin.attrs["noise_threads"] > 1
+
+    def test_scalar_draw_counts_one_draw_and_no_split(self, native):
+        assert self._deltas(
+            lambda: noise_core.add_laplace_noise(1.0, 2.0)) == [1, 0]
 
 
 class TestBitIdentity:
