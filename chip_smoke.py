@@ -4,7 +4,7 @@ the headline deployment shape.
     python chip_smoke.py             # one TPU chip: phases A, A', B
     python chip_smoke.py --chips 4   # one host, four chips: the mesh phase
 
-Data: the movie-ratings-shaped workload of ``bench.py`` — 100M rows,
+Data: a movie-ratings-shaped workload — 100M rows,
 1M partitions, 10M privacy units, partition popularity pk = P * u**4,
 integer star ratings 1..5 — generated from ``--seed``. The mesh phase
 runs the same shape at 10M rows (MESH_SHAPE says why).
@@ -139,10 +139,12 @@ def _sampler_counters() -> dict:
     from pipelinedp_tpu.ops import columnar
 
     return {
-        "hash_passes": profiler.event_count(columnar.EVENT_HASH_PASSES),
+        "hash_passes": profiler.event_count(columnar.sampler_event("hash")),
         "hash_demotions": profiler.event_count(
             columnar.EVENT_HASH_DEMOTIONS),
-        "sort_tiles": profiler.event_count(columnar.EVENT_SORT_TILES),
+        "sorted_chunks": sum(
+            profiler.event_count(columnar.sampler_event(kind))
+            for kind in ("tiled", "packed", "general")),
     }
 
 
@@ -154,7 +156,7 @@ def _sampler_name(before: dict, after: dict) -> str:
         return "hash"
     if d["hash_passes"]:
         return f"hash+{d['hash_demotions']}_demoted"
-    return "sorted" if d["sort_tiles"] else "none"
+    return "sorted" if d["sorted_chunks"] else "none"
 
 
 def _peak_bytes(devices) -> list:

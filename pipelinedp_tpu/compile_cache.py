@@ -1,6 +1,6 @@
 """Placement of JAX's persistent compilation cache for the entry points.
 
-Called at the top of ``chip_smoke.py``, ``bench.py`` and
+Called at the top of ``chip_smoke.py``, the benchmark's entry points and
 ``examples/movie_view_ratings/run_on_tpu.py`` — never on library import,
 so an application that embeds the library keeps its own cache policy.
 

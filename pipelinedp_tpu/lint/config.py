@@ -101,14 +101,13 @@ class LintConfig:
     #  * ops_plane._writable: the /healthz writability probe creates and
     #    unlinks a throwaway file; durability is the question it asks,
     #    not a property it needs.
-    #  * regress/profiler/lint: operator-facing report and cache
+    #  * profiler/lint: operator-facing report and cache
     #    artifacts — loss is repaired by re-running the tool.
     atomic_write_exempt: Tuple[str, ...] = (
         "pipelinedp_tpu.runtime.journal.JsonlWal.*",
         "pipelinedp_tpu.obs.flight.FlightRecorder.bind_spool",
         "pipelinedp_tpu.obs.flight.FlightRecorder._rotate_spool_locked",
         "pipelinedp_tpu.obs.ops_plane._writable",
-        "pipelinedp_tpu.obs.regress.*",
         "pipelinedp_tpu.profiler.*",
         "pipelinedp_tpu.lint.*",
     )

@@ -34,9 +34,6 @@ The operational plane (PR 13) serves and persists all of it:
   * :mod:`~pipelinedp_tpu.obs.ops_plane` — stdlib HTTP endpoints over
     a live fleet: ``/metrics``, ``/healthz``, ``/statusz``,
     ``/debug/flightz``. Knob: ``PIPELINEDP_TPU_OPS_PORT``.
-  * :mod:`~pipelinedp_tpu.obs.regress` — the bench-trajectory perf
-    regression gate (``python -m pipelinedp_tpu.obs.regress
-    BENCH_*.json``), wired into CI.
 
 DP-safety is a hard API rule, not a convention: raw pids, partition
 keys, and unreleased (pre-noise) values never enter any obs record —

@@ -19,8 +19,8 @@ namespace that absorbed ``profiler.count_event``:
     read the same storage exporters scrape.
 
 Exports: :meth:`MetricsRegistry.to_prometheus` (text exposition format)
-and :meth:`MetricsRegistry.snapshot` (a JSON-able dict; bench.py embeds
-it per row). ``PIPELINEDP_TPU_METRICS=<path>`` writes the exposition
+and :meth:`MetricsRegistry.snapshot` (a JSON-able dict).
+``PIPELINEDP_TPU_METRICS=<path>`` writes the exposition
 there at process exit (a ``.json`` suffix writes the snapshot instead).
 
 Atomicity contract (the PR-11 counter-hygiene fix): every registry
@@ -321,7 +321,7 @@ class MetricsRegistry:
     # -- exports ----------------------------------------------------------
 
     def snapshot(self) -> dict:
-        """JSON-able dump of everything (bench.py embeds this)."""
+        """JSON-able dump of everything."""
         with self._lock:
             families = {}
             for name, fam in self._families.items():

@@ -31,7 +31,7 @@ are the intended targets — or let ``PIPELINEDP_TPU_OPS_PORT`` start it
 automatically when a ``SessionManager`` is constructed. ``port=0``
 binds an ephemeral port (``server.port`` reports it). The server runs
 on daemon threads and holds no locks while rendering: it reads the
-same snapshot APIs bench.py does, so a wedged query cannot wedge the
+same snapshot APIs as any other reader, so a wedged query cannot wedge the
 diagnostics that would explain it, and the plane being up or down
 cannot change a single released bit (pinned by
 tests/obs_serving_test.py).

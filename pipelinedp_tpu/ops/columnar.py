@@ -219,30 +219,17 @@ def _sample_rows_and_groups(key: jax.Array, pid: jnp.ndarray,
 _MIN_RAND_BITS = 12
 _KEY_BITS = 96  # three uint32 sort keys
 
-# Profiler event counters for the bounding sorts (counted per EXECUTED
-# chunk kernel by the streaming drivers and by bench.py, from the static
-# sort_cost model below — the kernels themselves are jitted and cannot
-# count per execution):
-#   ops/sort_rows          rows entering the sampler sort (incl. tile pad)
-#   ops/sort_tiles         independently sorted tiles (1 for global sorts)
-#   ops/sort_operand_bytes modeled bytes the O(rows * log span) sort
-#                          network moves: rows * bytes_per_row * log2(span).
-#                          The tiled path shrinks it through the log factor
-#                          (span = tile width, not chunk rows) and the
-#                          narrowed value payload; the hash-binned group
-#                          stage makes ZERO sort passes, so it credits 0.
-EVENT_SORT_ROWS = "ops/sort_rows"
-EVENT_SORT_TILES = "ops/sort_tiles"
-EVENT_SORT_BYTES = "ops/sort_operand_bytes"
-
+# Executed chunk launches per sampler, one event per launch, counted by
+# the streaming drivers (the jitted kernels cannot count per execution):
+#   ops/sampler_chunks/<kind>  kind is sampler_kind() of the launched
+#                              program, after any hash demotion
 # Hash-binned (sortless) group-stage counters, per EXECUTED chunk:
-#   ops/hash_bin_passes              chunks that ran the hash-binned stage
 #   ops/hash_bin_occupancy_pct      cumulative per-chunk grid occupancy in
-#                                    percent (divide by passes for the mean)
+#                                    percent (divide by the hash chunks
+#                                    for the mean)
 #   ops/hash_bin_overflow_demotions chunks whose RLE entry count exceeded
 #                                    the static bin count and were demoted
 #                                    to the tiled sort by the host driver
-EVENT_HASH_PASSES = "ops/hash_bin_passes"
 EVENT_HASH_OCCUPANCY = "ops/hash_bin_occupancy_pct"
 EVENT_HASH_DEMOTIONS = "ops/hash_bin_overflow_demotions"
 
@@ -275,59 +262,33 @@ def presorted_fits(n: int, num_partitions: int,
     return randbits >= _MIN_RAND_BITS
 
 
-def sort_cost(n: int, *, num_partitions: int,
-              max_segments: Optional[int] = None, pid_sorted: bool = False,
-              tile_rows: int = 0, tile_slack: int = 0,
-              has_value: bool = True, value_bytes: int = 4,
-              need_order: bool = False, l1_mode: bool = False,
-              hash_bins: int = 0, hash_bin_rows: int = 0) -> dict:
-    """Static cost model of the sampler sort one kernel execution runs.
+def sampler_kind(n: int, *, num_partitions: int,
+                 max_segments: Optional[int], pid_sorted: bool,
+                 l1_mode: bool, tile_rows: int, tile_slack: int,
+                 hash_bins: int, hash_bin_rows: int) -> str:
+    """The sampler a bounding kernel runs at these statics: "empty"
+    (n <= 0), "hash", "tiled", "packed" or "general".
 
-    Mirrors _dispatch_sampler's trace-time dispatch exactly, so host
-    drivers can account the compiled kernel's sort without instrumenting
-    jitted code. Returns {kind, rows, span, tiles, bytes_per_row,
-    operand_bytes}: ``operand_bytes`` is the O(rows * log span) traffic
-    model ``rows * bytes_per_row * max(1, ceil(log2(span)))`` — the bytes
-    an O(N log N) sort network moves — credited to the profiler counters
-    EVENT_SORT_ROWS / EVENT_SORT_TILES / EVENT_SORT_BYTES per executed
-    chunk by the streaming drivers and bench.py.
-
-    kind "hash" (the sortless hash-binned group stage) reports its grid
-    geometry in rows/span/tiles but ZERO operand_bytes — the group stage
-    makes no sort pass over the wire at all.
+    The one place the choice is made: _dispatch_sampler switches on it
+    at trace time, and the host drivers and the serving bound-cache key
+    (streaming.resolved_sampler_desc) read it. n is the chunk's row
+    capacity (the traced ``pid.shape[0]``).
     """
     if n <= 0:
-        return {"kind": "empty", "rows": 0, "span": 1, "tiles": 0,
-                "bytes_per_row": 0, "operand_bytes": 0}
-    packed = (pid_sorted and not l1_mode
-              and presorted_fits(n, num_partitions, max_segments))
-    if packed:
-        bpr = 12 + (value_bytes if has_value else 0) + (4 if need_order
-                                                        else 0)
+        return "empty"
+    if (pid_sorted and not l1_mode
+            and presorted_fits(n, num_partitions, max_segments)):
         if hash_bins and hash_bin_rows:
-            return {"kind": "hash", "rows": hash_bins * hash_bin_rows,
-                    "span": hash_bin_rows, "tiles": hash_bins,
-                    "bytes_per_row": 0, "operand_bytes": 0}
+            return "hash"
         if tile_rows and tile_rows + tile_slack < n:
-            w = tile_rows + tile_slack
-            tiles = -(-n // tile_rows)
-            rows = tiles * w
-            return {"kind": "tiled", "rows": rows, "span": w,
-                    "tiles": tiles, "bytes_per_row": bpr,
-                    "operand_bytes":
-                        rows * bpr * max(1, (w - 1).bit_length())}
-        return {"kind": "packed", "rows": n, "span": n, "tiles": 1,
-                "bytes_per_row": bpr,
-                "operand_bytes": n * bpr * max(1, (n - 1).bit_length())}
-    # General 4-key sort: pid/ghash/pk/tiebreak keys + valid payload
-    # (+ order, + value); max_contributions mode pays the L1 pre-sample
-    # lexsort (2 keys + the implicit iota payload) on top.
-    bpr = 17 + (4 if need_order else 0) + (value_bytes if has_value else 0)
-    cost = n * bpr * max(1, (n - 1).bit_length())
-    if l1_mode:
-        cost += n * 12 * max(1, (n - 1).bit_length())
-    return {"kind": "general", "rows": n, "span": n, "tiles": 1,
-            "bytes_per_row": bpr, "operand_bytes": cost}
+            return "tiled"
+        return "packed"
+    return "general"
+
+
+def sampler_event(kind: str) -> str:
+    """Name of the executed-launch counter of one sampler kind."""
+    return f"ops/sampler_chunks/{kind}"
 
 
 def _pack_key_bits(fields) -> list:
@@ -799,27 +760,29 @@ def _dispatch_sampler(key, pid, pk, valid, linf_cap, l0_cap, l1_cap, *,
     :class:`BinnedRows` rank view instead of a sorted sequence —
     callers branch on the type at trace time).
 
-    Dispatch order on presorted ingest: hash-binned (sortless group
-    stage) when the grid geometry is set, else tiled, else the packed
-    global sort; the general 4-key sort otherwise.
+    The choice is sampler_kind's (hash-binned, tiled, packed global or
+    general 4-key sort); this function only switches on it.
     """
     n = pid.shape[0]
+    kind = sampler_kind(
+        n, num_partitions=num_partitions, max_segments=max_segments,
+        pid_sorted=pid_sorted, l1_mode=l1_cap is not None,
+        tile_rows=tile_rows, tile_slack=tile_slack, hash_bins=hash_bins,
+        hash_bin_rows=hash_bin_rows)
+    max_seg = int(max_segments) if max_segments else n
     # dplint: disable=DPL003 — static/structural branch, resolved per compile
-    if (pid_sorted and l1_cap is None
-            and presorted_fits(n, num_partitions, max_segments)):
-        max_seg = int(max_segments) if max_segments else n
-        if hash_bins and hash_bin_rows:
-            return _bin_rows_and_groups_hash(
-                key, pid, pk, valid, linf_cap, l0_cap,
-                num_partitions=num_partitions, max_segments=max_seg,
-                hash_bins=hash_bins, hash_bin_rows=hash_bin_rows,
-                value=value)
-        if tile_rows and tile_rows + tile_slack < n:
-            return _sample_rows_and_groups_tiled(
-                key, pid, pk, valid, linf_cap, l0_cap,
-                num_partitions=num_partitions, max_segments=max_seg,
-                tile_rows=tile_rows, tile_slack=tile_slack, value=value,
-                need_order=need_order)
+    if kind == "hash":
+        return _bin_rows_and_groups_hash(
+            key, pid, pk, valid, linf_cap, l0_cap,
+            num_partitions=num_partitions, max_segments=max_seg,
+            hash_bins=hash_bins, hash_bin_rows=hash_bin_rows, value=value)
+    if kind == "tiled":
+        return _sample_rows_and_groups_tiled(
+            key, pid, pk, valid, linf_cap, l0_cap,
+            num_partitions=num_partitions, max_segments=max_seg,
+            tile_rows=tile_rows, tile_slack=tile_slack, value=value,
+            need_order=need_order)
+    if kind == "packed":
         return _sample_rows_and_groups_presorted(
             key, pid, pk, valid, linf_cap, l0_cap,
             num_partitions=num_partitions, max_segments=max_seg,

@@ -105,26 +105,19 @@ COMPACT_MIN_PARTITIONS = 1 << 17
 
 def finish_wire_plan(fmt, segment_sort, max_run, *, num_partitions: int,
                      row_clip_lo, row_clip_hi, linf_cap, l1_mode: bool,
-                     with_quantile_mask: bool = False,
                      group_clip_lo=-np.inf, group_clip_hi=np.inf,
                      need_flags=(True, True, True, True)):
-    """Finalizes a wire format for the chunk kernels -> (fmt, int_clip,
-    sort_stats). Shared by the single-device slab loop and the mesh chunk
-    loop (parallel/sharded.py) so both paths resolve the segment_sort
-    knob, the int32-accumulation gate, and the per-chunk sort cost
-    identically.
+    """Finalizes a wire format for the chunk kernels -> (fmt, int_clip).
+    Shared by the single-device slab loop and the mesh chunk loop
+    (parallel/sharded.py) so both paths resolve the segment_sort knob
+    and the int32-accumulation gate identically.
 
     fmt gains tile geometry and (segment_sort "hash", or "auto" under
     the order-exactness gate) the hash-bin grid of the sortless group
     stage (wirecodec.plan_group_binning — the 4-way
     general/packed/tiled/hash dispatch); int_clip is the int32 row-clip
     pair when VALUE_PLANES chunks may accumulate in int32 bit-identically
-    (columnar.int_accumulation_plan), else None; sort_stats is the
-    columnar.sort_cost dict one executed chunk kernel credits to the
-    ops/sort_* counters (plus the replayed row-mask sort when the chunk
-    also feeds quantile histograms), its resolved ``kind``, and — when
-    the hash grid is planned — the ``demoted`` stats of the per-chunk
-    tiled fallback plus the ``grid_cells`` occupancy denominator.
+    (columnar.int_accumulation_plan), else None.
 
     "auto" picks the hash-binned stage only when it is provably
     bit-identical to the sorted paths: columnar.hash_exact_gate holds
@@ -161,37 +154,27 @@ def finish_wire_plan(fmt, segment_sort, max_run, *, num_partitions: int,
                                            exact=exact)
         if clip is not None:
             clip = (np.int32(clip[0]), np.int32(clip[1]))
-    vb = 4
-    if (fmt.value.mode == wirecodec.VALUE_PLANES
-            and fmt.sort_value_narrow):
-        vb = 1 if fmt.value.bits <= 8 else (
-            2 if fmt.value.bits <= 16 else 4)
+    return fmt, clip
 
-    def cost_stats(hash_bins, hash_bin_rows):
-        tiles = ((fmt.tile_rows, fmt.tile_slack) if fmt.pid_sorted
-                 else (0, 0))
-        kw = dict(num_partitions=num_partitions,
-                  max_segments=fmt.ucap if fmt.pid_sorted else None,
-                  pid_sorted=fmt.pid_sorted, tile_rows=tiles[0],
-                  tile_slack=tiles[1], hash_bins=hash_bins,
-                  hash_bin_rows=hash_bin_rows, l1_mode=l1_mode)
-        cost = columnar.sort_cost(fmt.cap, value_bytes=vb, **kw)
-        out = {name: cost[name]
-               for name in ("rows", "tiles", "operand_bytes")}
-        if with_quantile_mask:
-            mask = columnar.sort_cost(fmt.cap, has_value=False,
-                                      need_order=True, **kw)
-            for name in ("rows", "tiles", "operand_bytes"):
-                out[name] += mask[name]
-        out["kind"] = cost["kind"]
-        return out
 
-    hb = (fmt.hash_bins, fmt.hash_bin_rows) if fmt.pid_sorted else (0, 0)
-    stats = cost_stats(*hb)
-    if hb[0]:
-        stats["demoted"] = cost_stats(0, 0)
-        stats["grid_cells"] = hb[0] * hb[1]
-    return fmt, clip, stats
+def _sampler_geometry(fmt) -> dict:
+    """The tile and hash statics a wire chunk kernel hands its sampler:
+    the format's geometry on a pid-sorted wire, none otherwise."""
+    on = fmt.pid_sorted
+    return dict(tile_rows=fmt.tile_rows if on else 0,
+                tile_slack=fmt.tile_slack if on else 0,
+                hash_bins=fmt.hash_bins if on else 0,
+                hash_bin_rows=fmt.hash_bin_rows if on else 0)
+
+
+def wire_sampler_kind(fmt, *, num_partitions: int, l1_mode: bool) -> str:
+    """columnar.sampler_kind of the chunk kernels compiled against a
+    finished wire format, from the statics they pass."""
+    return columnar.sampler_kind(
+        fmt.cap, num_partitions=num_partitions,
+        max_segments=fmt.ucap if fmt.pid_sorted else None,
+        pid_sorted=fmt.pid_sorted, l1_mode=l1_mode,
+        **_sampler_geometry(fmt))
 
 
 def resolved_sampler_desc(fmt, segment_sort, max_run, *,
@@ -211,23 +194,14 @@ def resolved_sampler_desc(fmt, segment_sort, max_run, *,
     path gets the same guarantee from ``repr(fmt)`` riding the wire
     fingerprint).
     """
-    fmt2, int_clip, stats = finish_wire_plan(
+    fmt2, _ = finish_wire_plan(
         fmt, segment_sort, max_run, num_partitions=num_partitions,
         row_clip_lo=row_clip_lo, row_clip_hi=row_clip_hi,
         linf_cap=linf_cap, l1_mode=l1_mode, group_clip_lo=group_clip_lo,
         group_clip_hi=group_clip_hi, need_flags=tuple(need_flags))
-    return f"{stats['kind']}:{fmt2!r}"
-
-
-def _count_sort_stats(stats) -> None:
-    """Credits one executed chunk kernel's sort cost to the ops/sort_*
-    profiler counters (columnar.sort_cost model — the jitted kernels
-    cannot count per execution, so the drivers do it per dispatched
-    chunk)."""
-    profiler.count_event(columnar.EVENT_SORT_ROWS, int(stats["rows"]))
-    profiler.count_event(columnar.EVENT_SORT_TILES, int(stats["tiles"]))
-    profiler.count_event(columnar.EVENT_SORT_BYTES,
-                         int(stats["operand_bytes"]))
+    kind = wire_sampler_kind(fmt2, num_partitions=num_partitions,
+                             l1_mode=l1_mode)
+    return f"{kind}:{fmt2!r}"
 
 
 def _compact_enabled(compact_merge, num_partitions: int) -> bool:
@@ -385,10 +359,7 @@ def _decode_for_kernel(row, n_valid, n_uniq, fmt):
             value = jnp.zeros((fmt.cap,), dtype=jnp.float32)
             value_as_index = False
     kwargs = dict(
-        tile_rows=fmt.tile_rows if fmt.pid_sorted else 0,
-        tile_slack=fmt.tile_slack if fmt.pid_sorted else 0,
-        hash_bins=fmt.hash_bins if fmt.pid_sorted else 0,
-        hash_bin_rows=fmt.hash_bin_rows if fmt.pid_sorted else 0,
+        _sampler_geometry(fmt),
         value_is_index=value_as_index,
         value_lo=np.float32(fmt.value.lo),
         value_scale=np.float32(fmt.value.scale),
@@ -512,25 +483,10 @@ def _merge_pending(accs, pending, num_partitions, need_flags):
         need_flags=tuple(need_flags))
 
 
-def _credit_chunk_stats(stats, n_valid) -> None:
-    """Per-executed-chunk counter crediting: the sort-cost model plus
-    the hash-bin pass/occupancy counters (the drivers' host-side twin
-    of the jitted kernels, which cannot count per execution)."""
-    if stats is None:
-        return
-    _count_sort_stats(stats)
-    if stats.get("kind") == "hash":
-        profiler.count_event(columnar.EVENT_HASH_PASSES)
-        cells = max(int(stats.get("grid_cells", 0)), 1)
-        profiler.count_event(columnar.EVENT_HASH_OCCUPANCY,
-                             min(100, (100 * int(n_valid)) // cells))
-
-
 def _build_chunk_steps(key, fmt, int_clip, *, num_partitions, linf_cap,
                        l0_cap, row_clip_lo, row_clip_hi, middle,
                        group_clip_lo, group_clip_hi, l1_cap, need_flags,
-                       has_group_clip, quantile_spec, compact_merge,
-                       sort_stats=None):
+                       has_group_clip, quantile_spec, compact_merge):
     """(step_chunk, compact_step, merge_fn) for one finished wire format.
 
     The single place the per-chunk kernel closures are built, shared by
@@ -546,10 +502,9 @@ def _build_chunk_steps(key, fmt, int_clip, *, num_partitions, linf_cap,
     part of the wire fingerprint, so cold runs, warm replays and
     resumes demote identically and released bits never depend on it.
 
-    sort_stats (finish_wire_plan) makes the steps credit the executed
-    sort-cost model and hash-bin counters per chunk — per-chunk because
-    demoted chunks must credit the fallback cost, which the driver's
-    single on_chunk hook cannot distinguish.
+    Each launch counts ``ops/sampler_chunks/<kind>`` of the kernel it
+    runs (the demoted kind for demoted chunks) and, on the hash stage,
+    its grid occupancy.
 
     compact_step/merge_fn are None when the compact merge does not apply
     (knob off, too few partitions, PID_PLANES wire — no per-chunk pid
@@ -558,14 +513,23 @@ def _build_chunk_steps(key, fmt, int_clip, *, num_partitions, linf_cap,
     hash_on = fmt.hash_bins > 0 and fmt.pid_sorted
     fmt_demoted = (dataclasses.replace(fmt, hash_bins=0, hash_bin_rows=0)
                    if hash_on else fmt)
+    l1_mode = l1_cap is not None
+    kind = wire_sampler_kind(fmt, num_partitions=num_partitions,
+                             l1_mode=l1_mode)
+    kind_demoted = wire_sampler_kind(fmt_demoted,
+                                     num_partitions=num_partitions,
+                                     l1_mode=l1_mode)
+    cells = max(fmt.hash_bins * fmt.hash_bin_rows, 1)
 
     def chunk_plan(n_uniq_c, n_valid):
         if hash_on and n_uniq_c > fmt.hash_bins:
             profiler.count_event(columnar.EVENT_HASH_DEMOTIONS)
-            demoted = (sort_stats or {}).get("demoted")
-            _credit_chunk_stats(demoted, n_valid)
+            profiler.count_event(columnar.sampler_event(kind_demoted))
             return fmt_demoted
-        _credit_chunk_stats(sort_stats, n_valid)
+        profiler.count_event(columnar.sampler_event(kind))
+        if kind == "hash":
+            profiler.count_event(columnar.EVENT_HASH_OCCUPANCY,
+                                 min(100, (100 * int(n_valid)) // cells))
         return fmt
 
     def step_chunk(c, bucket_row, accs, qhist, n_valid, n_uniq_c):
@@ -786,7 +750,7 @@ def stream_bound_and_aggregate(
                 pid, pk, value, num_partitions=num_partitions, k=k,
                 value_transfer_dtype=value_transfer_dtype)
 
-        # `fmt`, `int_clip` and `sort_stats` are late-bound from the
+        # `fmt` and `int_clip` are late-bound from the
         # enclosing scope: both encode branches below run
         # _finish_wire_plan before the slab loop makes the first call.
         def _finish_wire_plan(wire_fmt):
@@ -795,11 +759,10 @@ def stream_bound_and_aggregate(
                 num_partitions=num_partitions, row_clip_lo=row_clip_lo,
                 row_clip_hi=row_clip_hi, linf_cap=linf_cap,
                 l1_mode=l1_cap is not None,
-                with_quantile_mask=quantile_spec is not None,
                 group_clip_lo=group_clip_lo, group_clip_hi=group_clip_hi,
                 need_flags=tuple(need_flags))
 
-        def build_steps(fmt, int_clip, sort_stats):
+        def build_steps(fmt, int_clip):
             return _build_chunk_steps(
                 key, fmt, int_clip, num_partitions=num_partitions,
                 linf_cap=linf_cap, l0_cap=l0_cap, row_clip_lo=row_clip_lo,
@@ -807,7 +770,7 @@ def stream_bound_and_aggregate(
                 group_clip_lo=group_clip_lo, group_clip_hi=group_clip_hi,
                 l1_cap=l1_cap, need_flags=need_flags,
                 has_group_clip=has_group_clip, quantile_spec=quantile_spec,
-                compact_merge=compact_merge, sort_stats=sort_stats)
+                compact_merge=compact_merge)
 
         scatter_passes = 1 + sum(bool(f) for f in need_flags)
 
@@ -854,7 +817,7 @@ def stream_bound_and_aggregate(
                         cap=cap,
                         ucap=wirecodec.round_ucap(int(n_uniq.max())),
                         value=info.plan)
-                fmt, int_clip, sort_stats = _finish_wire_plan(fmt)
+                fmt, int_clip = _finish_wire_plan(fmt)
                 budget = slab_byte_budget(pipelined_sort)
                 n_t = n_transfers or _num_transfers(fmt.width * k, k,
                                                     budget)
@@ -876,7 +839,7 @@ def stream_bound_and_aggregate(
                         return enc.emit_range(s0, s1, fmt)
 
                 step_chunk, compact_step, merge_fn = build_steps(
-                    fmt, int_clip, sort_stats)
+                    fmt, int_clip)
                 accs, qhist = _drive_slab_windows(
                     key, k, counts, n_uniq, fmt, prepare_slab, step_chunk,
                     n_t, num_partitions, quantile_spec, resilience,
@@ -890,10 +853,10 @@ def stream_bound_and_aggregate(
                     bytes_pid=info.bytes_pid, bits_pk=info.bits_pk,
                     plan=info.plan, pid_mode=info.pid_mode,
                     bits_pid=info.bits_pid)
-            fmt, int_clip, sort_stats = _finish_wire_plan(fmt)
+            fmt, int_clip = _finish_wire_plan(fmt)
             n_t = n_transfers or _num_transfers(slab.nbytes, k)
             step_chunk, compact_step, merge_fn = build_steps(
-                fmt, int_clip, sort_stats)
+                fmt, int_clip)
             accs, qhist = _drive_slab_windows(
                 key, k, counts, n_uniq, fmt,
                 lambda s0, s1: slab[s0:s1], step_chunk,
@@ -932,7 +895,13 @@ def stream_bound_and_aggregate(
     # this one still overlaps the current slab's kernels (async dispatch).
     n_t = n_transfers or _num_transfers(buckets.nbytes, k)
 
+    bytes_event = columnar.sampler_event(columnar.sampler_kind(
+        int(buckets.shape[1]), num_partitions=num_partitions,
+        max_segments=None, pid_sorted=False, l1_mode=l1_cap is not None,
+        tile_rows=0, tile_slack=0, hash_bins=0, hash_bin_rows=0))
+
     def step_chunk_bytes(c, bucket_row, accs, qhist, n_valid, _n_uniq_c):
+        profiler.count_event(bytes_event)
         return _chunk_step(jax.random.fold_in(key, c), bucket_row,
                            n_valid, accs,
                            linf_cap, l0_cap, row_clip_lo,
@@ -945,18 +914,13 @@ def stream_bound_and_aggregate(
                            need_flags=tuple(need_flags),
                            has_group_clip=has_group_clip), qhist
 
-    bytes_cost = columnar.sort_cost(int(buckets.shape[1]),
-                                    num_partitions=num_partitions,
-                                    l1_mode=l1_cap is not None)
     accs, _ = _drive_slab_windows(
         key, k, counts, None,
         ("bytes", bytes_pid, bytes_pk, value_f16, width),
         lambda s0, s1: buckets[s0:s1], step_chunk_bytes,
         n_t, num_partitions, None, resilience,
         lambda: _input_digest(pid, pk, value),
-        scatter_passes=1 + sum(bool(f) for f in need_flags),
-        sort_stats={name: bytes_cost[name]
-                    for name in ("rows", "tiles", "operand_bytes")})
+        scatter_passes=1 + sum(bool(f) for f in need_flags))
     return accs
 
 
@@ -1052,8 +1016,7 @@ class _SingleDevicePlacement(driver_lib.DevicePlacement):
 def _drive_slab_windows(key, k, counts, n_uniq, fmt_desc, prepare_slab,
                         step_chunk, n_transfers, num_partitions,
                         quantile_spec, resilience, data_digest_fn=None, *,
-                        compact_step=None, merge_fn=None, scatter_passes=5,
-                        sort_stats=None):
+                        compact_step=None, merge_fn=None, scatter_passes=5):
     """Runs the single-device streaming schedule on the unified slab
     driver (runtime.SlabDriver — checkpoint/resume, retry + OOM window
     degradation, lookahead prefetch, compact merge, fault injection and
@@ -1081,8 +1044,6 @@ def _drive_slab_windows(key, k, counts, n_uniq, fmt_desc, prepare_slab,
         scatter_passes=scatter_passes,
         quantile=quantile_spec is not None,
         data_digest_fn=data_digest_fn,
-        on_chunk=((lambda: _count_sort_stats(sort_stats))
-                  if sort_stats is not None else None),
         prefetch_depth=prefetch_depth())
     return driver_lib.SlabDriver(placement, plan, prepare_slab, key,
                                  resilience).run()
@@ -1567,12 +1528,11 @@ def replay_resident_wire(key: jax.Array,
     profiler.count_event(EVENT_SERVING_REPLAYS)
     obs_trace.event("wire_replay", n_chunks=wire.n_chunks,
                     device_resident=wire.device_resident)
-    fmt, int_clip, sort_stats = finish_wire_plan(
+    fmt, int_clip = finish_wire_plan(
         wire.fmt, segment_sort, wire.max_run,
         num_partitions=num_partitions, row_clip_lo=row_clip_lo,
         row_clip_hi=row_clip_hi, linf_cap=linf_cap,
         l1_mode=l1_cap is not None,
-        with_quantile_mask=quantile_spec is not None,
         group_clip_lo=group_clip_lo, group_clip_hi=group_clip_hi,
         need_flags=tuple(need_flags))
     step_chunk, compact_step, merge_fn = _build_chunk_steps(
@@ -1582,7 +1542,7 @@ def replay_resident_wire(key: jax.Array,
         group_clip_lo=group_clip_lo, group_clip_hi=group_clip_hi,
         l1_cap=l1_cap, need_flags=need_flags,
         has_group_clip=has_group_clip, quantile_spec=quantile_spec,
-        compact_merge=compact_merge, sort_stats=sort_stats)
+        compact_merge=compact_merge)
     k = wire.k
     placement = _ResidentReplayPlacement(
         device_slab=wire._device_slab,
@@ -1726,10 +1686,8 @@ def replay_resident_wire_batched(keys,
     k = wire.k
     n_t = n_transfers or _num_transfers(wire.slab.nbytes, k)
     window = max(1, (k + n_t - 1) // n_t)
-    cost = columnar.sort_cost(
-        fmt.cap, num_partitions=num_partitions,
-        max_segments=fmt.ucap if fmt.pid_sorted else None,
-        pid_sorted=fmt.pid_sorted, l1_mode=l1 is not None)
+    kind_event = columnar.sampler_event(wire_sampler_kind(
+        fmt, num_partitions=num_partitions, l1_mode=l1 is not None))
     for s0 in range(0, k, window):
         s1 = min(s0 + window, k)
         if wire._device_slab is not None:
@@ -1743,11 +1701,7 @@ def replay_resident_wire_batched(keys,
                 ghi, l1, num_partitions=num_partitions, fmt=fmt,
                 need_flags=tuple(need_flags),
                 has_group_clip=has_group_clip)
-            # ONE launch covers all B configs; the sort model runs B
-            # lanes over the chunk's rows.
+            # ONE launch covers all B configs.
             profiler.count_event(EVENT_SERVING_LAUNCHES)
-            profiler.count_event(columnar.EVENT_SORT_ROWS,
-                                 int(cost["rows"]) * B)
-            profiler.count_event(columnar.EVENT_SORT_BYTES,
-                                 int(cost["operand_bytes"]) * B)
+            profiler.count_event(kind_event)
     return accs

@@ -844,11 +844,11 @@ def stream_bound_and_aggregate(mesh: Mesh,
                 def emit(c):
                     return enc.emit_range(c * n_dev, (c + 1) * n_dev, fmt)
 
-            # Tile geometry + int-accumulation gate + per-bucket sort cost,
-            # resolved exactly as on the single-device path (tile fields
-            # are sort geometry, not wire layout, so the emit closures
-            # above are unaffected by the replace).
-            fmt, int_clip, sort_stats = streaming.finish_wire_plan(
+            # Tile geometry + int-accumulation gate, resolved exactly as
+            # on the single-device path (tile fields are sort geometry,
+            # not wire layout, so the emit closures above are unaffected
+            # by the replace).
+            fmt, int_clip = streaming.finish_wire_plan(
                 fmt, segment_sort, info.max_run,
                 num_partitions=padded_p, row_clip_lo=row_clip_lo,
                 row_clip_hi=row_clip_hi, linf_cap=linf_cap,
@@ -864,13 +864,12 @@ def stream_bound_and_aggregate(mesh: Mesh,
                                      lambda: streaming._input_digest(
                                          pid, pk, value),
                                      compact_merge=compact_merge,
-                                     int_clip=int_clip,
-                                     sort_stats=sort_stats)
+                                     int_clip=int_clip)
     slab, counts, n_uniq, fmt = wirecodec.encode_buckets_numpy(
         pid, pk, value, pid_lo=info.pid_lo, k=k, bytes_pid=info.bytes_pid,
         bits_pk=info.bits_pk, plan=info.plan, pid_mode=info.pid_mode,
         bits_pid=info.bits_pid)
-    fmt, int_clip, sort_stats = streaming.finish_wire_plan(
+    fmt, int_clip = streaming.finish_wire_plan(
         fmt, segment_sort, info.max_run,
         num_partitions=padded_p, row_clip_lo=row_clip_lo,
         row_clip_hi=row_clip_hi, linf_cap=linf_cap,
@@ -886,7 +885,7 @@ def stream_bound_and_aggregate(mesh: Mesh,
                              has_group_clip, resilience,
                              lambda: streaming._input_digest(pid, pk, value),
                              compact_merge=compact_merge,
-                             int_clip=int_clip, sort_stats=sort_stats)
+                             int_clip=int_clip)
 
 
 def replay_resident_wire(mesh: Mesh,
@@ -930,7 +929,7 @@ def replay_resident_wire(mesh: Mesh,
     profiler.count_event(streaming.EVENT_SERVING_REPLAYS)
     from pipelinedp_tpu.obs import trace as obs_trace
     obs_trace.event("wire_replay", n_chunks=wire.n_chunks, n_dev=n_dev)
-    fmt, int_clip, sort_stats = streaming.finish_wire_plan(
+    fmt, int_clip = streaming.finish_wire_plan(
         wire.fmt, segment_sort, wire.max_run, num_partitions=padded_p,
         row_clip_lo=row_clip_lo, row_clip_hi=row_clip_hi,
         linf_cap=linf_cap, l1_mode=l1_cap is not None,
@@ -941,8 +940,7 @@ def replay_resident_wire(mesh: Mesh,
         wire.counts, wire.n_uniq, fmt, wire.n_chunks, n_dev, padded_p,
         linf_cap, l0_cap, row_clip_lo, row_clip_hi, middle, group_clip_lo,
         group_clip_hi, l1_cap, tuple(need_flags), has_group_clip,
-        resilience, None, compact_merge=compact_merge, int_clip=int_clip,
-        sort_stats=sort_stats)
+        resilience, None, compact_merge=compact_merge, int_clip=int_clip)
 
 
 def _reduce_scatter_lanes(x, scatter_axes):
@@ -1089,10 +1087,8 @@ def replay_resident_wire_batched(mesh: Mesh,
     sharding = NamedSharding(mesh, _spec(mesh))
     counts = np.asarray(wire.counts, dtype=np.int32)
     n_uniq = np.asarray(wire.n_uniq, dtype=np.int32)
-    cost = columnar.sort_cost(
-        fmt.cap, num_partitions=padded_p,
-        max_segments=fmt.ucap if fmt.pid_sorted else None,
-        pid_sorted=fmt.pid_sorted, l1_mode=l1 is not None)
+    kind_event = columnar.sampler_event(streaming.wire_sampler_kind(
+        fmt, num_partitions=padded_p, l1_mode=l1 is not None))
     accs = None
     for c in range(wire.n_chunks):
         dslab = jax.device_put(wire.slab[c * n_dev:(c + 1) * n_dev],
@@ -1112,10 +1108,7 @@ def replay_resident_wire_batched(mesh: Mesh,
                     *(a + b for a, b in zip(accs, chunk_accs))))
         # ONE launch covers all B configs across n_dev bucket stages.
         profiler.count_event(streaming.EVENT_SERVING_LAUNCHES)
-        profiler.count_event(columnar.EVENT_SORT_ROWS,
-                             int(cost["rows"]) * B * n_dev)
-        profiler.count_event(columnar.EVENT_SORT_BYTES,
-                             int(cost["operand_bytes"]) * B * n_dev)
+        profiler.count_event(kind_event)
     return accs
 
 
@@ -1180,7 +1173,7 @@ def _drive_codec_chunks(mesh, key, emit, counts, n_uniq, fmt, n_c, n_dev,
                         row_clip_hi, middle, group_clip_lo, group_clip_hi,
                         l1_cap, need_flags, has_group_clip, resilience=None,
                         data_digest_fn=None, compact_merge: bool = True,
-                        int_clip=None, sort_stats=None):
+                        int_clip=None):
     """Runs the mesh chunk schedule on the unified slab driver
     (runtime.SlabDriver — the same loop body as the single-device path,
     so checkpoint/resume, retry, prefetch, compact merge, fault
@@ -1233,21 +1226,14 @@ def _drive_codec_chunks(mesh, key, emit, counts, n_uniq, fmt, n_c, n_dev,
     counts = np.asarray(counts, dtype=np.int32)
     n_uniq = np.asarray(n_uniq, dtype=np.int32)
 
-    def credit(st, rows):
-        # Every device sorts (or hash-bins) its own bucket, so one chunk
-        # executes n_dev bucket stages; the hash pass/occupancy counters
-        # count per LAUNCH (one chunk = one kernel), like the demotion
-        # counter.
-        if st is None:
-            return
-        streaming._count_sort_stats(
-            {name: st[name] * n_dev
-             for name in ("rows", "tiles", "operand_bytes")})
-        if st.get("kind") == "hash":
-            profiler.count_event(columnar.EVENT_HASH_PASSES)
-            cells = max(int(st.get("grid_cells", 0)) * n_dev, 1)
-            profiler.count_event(columnar.EVENT_HASH_OCCUPANCY,
-                                 min(100, (100 * rows) // cells))
+    # The sampler counters count per LAUNCH (one chunk = one kernel over
+    # n_dev buckets), like the demotion counter.
+    l1_mode = l1_cap is not None
+    kind = streaming.wire_sampler_kind(fmt, num_partitions=padded_p,
+                                       l1_mode=l1_mode)
+    kind_demoted = streaming.wire_sampler_kind(
+        fmt_demoted, num_partitions=padded_p, l1_mode=l1_mode)
+    cells = max(fmt.hash_bins * fmt.hash_bin_rows * n_dev, 1)
 
     def transfer_chunk(slab, c):
         dslab = jax.device_put(slab, sharding)
@@ -1259,13 +1245,18 @@ def _drive_codec_chunks(mesh, key, emit, counts, n_uniq, fmt, n_c, n_dev,
 
     def run_chunk(c, payload):
         dslab, dvalid, duniq = payload
-        use_kernel, st = kernel, sort_stats
         if (hash_on and int(n_uniq[c * n_dev:(c + 1) * n_dev].max())
                 > fmt.hash_bins):
             profiler.count_event(columnar.EVENT_HASH_DEMOTIONS)
+            profiler.count_event(columnar.sampler_event(kind_demoted))
             use_kernel = build_kernel(fmt_demoted)
-            st = (sort_stats or {}).get("demoted")
-        credit(st, int(counts[c * n_dev:(c + 1) * n_dev].sum()))
+        else:
+            profiler.count_event(columnar.sampler_event(kind))
+            if kind == "hash":
+                rows = int(counts[c * n_dev:(c + 1) * n_dev].sum())
+                profiler.count_event(columnar.EVENT_HASH_OCCUPANCY,
+                                     min(100, (100 * rows) // cells))
+            use_kernel = kernel
         args = (jax.random.fold_in(key, c), dslab, dvalid, duniq,
                 linf_cap, l0_cap, float(row_clip_lo), float(row_clip_hi),
                 float(middle), float(group_clip_lo), float(group_clip_hi))

@@ -116,8 +116,9 @@ class StreamResilience:
 
 
 def resilience_counters() -> Dict[str, int]:
-    """Snapshot of the runtime's resilience counters (bench.py surfaces
-    this dict; all keys always present so dashboards can rely on them)."""
+    """Snapshot of the runtime's resilience counters (the benchmark and
+    chip_smoke.py report it; all keys always present so dashboards can
+    rely on them)."""
     return {
         "retries": profiler.event_count(EVENT_RETRIES),
         "degradations": profiler.event_count(EVENT_DEGRADATIONS),

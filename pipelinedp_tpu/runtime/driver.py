@@ -63,7 +63,7 @@ from pipelinedp_tpu.runtime import retry as retry_lib
 from pipelinedp_tpu.runtime import watchdog as watchdog_lib
 
 # Profiler event counters owned by the slab loop (profiler.count_event /
-# event_count; surfaced by runtime.resilience_counters and bench.py).
+# event_count; surfaced by runtime.resilience_counters).
 EVENT_RETRIES = "runtime/retries"
 EVENT_DEGRADATIONS = "runtime/degradations"
 EVENT_RESUMES = "runtime/resumes"
@@ -73,7 +73,7 @@ EVENT_CHECKPOINT_BYTES = "runtime/checkpoint_bytes"
 EVENT_HANGS = "runtime/hangs_detected"
 
 # Per-executed-chunk counters (canonical here; ops/streaming re-exports
-# them under the same names for bench.py and the test suites):
+# them under the same names for the test suites):
 #   EVENT_PARTITION_SCATTERS — full-[num_partitions] scatter passes whose
 #     input is row/group scale (one set per chunk on the legacy path);
 #   EVENT_COMPACT_CHUNKS — chunks that emitted compact group columns
@@ -181,9 +181,7 @@ class SlabPlan:
 
     fmt_desc is an opaque description of the wire layout (it enters the
     checkpoint wire fingerprint verbatim, so it must be stable across
-    the checkpointing and resuming processes). on_chunk, when set, is
-    called once per executed chunk (the sort-cost counter crediting the
-    jitted kernels cannot do per execution). prefetch_depth bounds the
+    the checkpointing and resuming processes). prefetch_depth bounds the
     background host-encode lookahead (0 disables).
 
     retain_sink, when set, is the driver's retain-wire mode: it is
@@ -208,7 +206,6 @@ class SlabPlan:
     scatter_passes: int = 5
     quantile: bool = False
     data_digest_fn: Optional[Callable[[], str]] = None
-    on_chunk: Optional[Callable[[], None]] = None
     prefetch_depth: int = 0
     retain_sink: Optional[Callable[[int, int, Any], None]] = None
 
@@ -412,8 +409,6 @@ class SlabDriver:
                                     profiler.count_event(
                                         EVENT_PARTITION_SCATTERS,
                                         plan.scatter_passes)
-                            if plan.on_chunk is not None:
-                                plan.on_chunk()
                             cursor = c + 1
                         if wd is not None:
                             # The per-window progress bound. A timeout

@@ -126,7 +126,7 @@ def append_commit_window_s() -> float:
 
 
 def live_counters() -> Dict[str, int]:
-    """Snapshot of the live-session counters (bench.py surfaces this)."""
+    """Snapshot of the live-session counters."""
     return {
         "appends": profiler.event_count(EVENT_APPENDS),
         "append_duplicates": profiler.event_count(EVENT_APPEND_DUPLICATES),

@@ -79,7 +79,7 @@ class SessionOverloadedError(RuntimeError):
 
 def fleet_counters(manager: Optional["SessionManager"] = None
                    ) -> Dict[str, int]:
-    """Snapshot of the fleet counters (bench.py surfaces this).
+    """Snapshot of the fleet counters.
     ``sessions_resident``/``sessions_spilled`` are gauges of the given
     manager; the rest are process-wide monotonic counters."""
     out = {

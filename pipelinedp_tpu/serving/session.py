@@ -139,9 +139,8 @@ def default_deadline_s() -> Optional[float]:
 
 
 def serving_counters() -> Dict[str, int]:
-    """Snapshot of the serving counters (bench.py surfaces this; the
-    fleet-level admission/demotion counters ride
-    serving.fleet_counters())."""
+    """Snapshot of the serving counters (the fleet-level
+    admission/demotion counters ride serving.fleet_counters())."""
     return {
         "queries": profiler.event_count(EVENT_QUERIES),
         "bound_cache_hits": profiler.event_count(EVENT_BOUND_HITS),

@@ -44,6 +44,14 @@ def _reset_counters():
     yield
 
 
+def _sampler_chunks() -> dict:
+    """Executed chunk launches per sampler kind since the last reset
+    (kinds that ran none are left out)."""
+    counts = {kind: profiler.event_count(columnar.sampler_event(kind))
+              for kind in ("hash", "tiled", "packed", "general")}
+    return {kind: n for kind, n in counts.items() if n}
+
+
 def _rle_data(n=60_000, n_parts=300, seed=0, integer_values=True):
     """Repetitive pids (~20 rows/user) -> PID_RLE wire with small
     max_run; integer values -> VALUE_PLANES -> the exactness gate can
@@ -96,11 +104,9 @@ class TestHashParityMatrix:
         profiler.reset_events("ops/")
         hashed = _stream(pid, pk, value, has_group_clip=has_group_clip,
                          compact_merge=compact, segment_sort="hash")
-        # Non-vacuous: every chunk ran the sortless stage, whose group
-        # stage moves ZERO sort operand bytes.
-        assert profiler.event_count(columnar.EVENT_HASH_PASSES) == 8
+        # Non-vacuous: every chunk ran the sortless stage, none a sort.
+        assert _sampler_chunks() == {"hash": 8}
         assert profiler.event_count(columnar.EVENT_HASH_DEMOTIONS) == 0
-        assert profiler.event_count(columnar.EVENT_SORT_BYTES) == 0
         assert profiler.event_count(columnar.EVENT_HASH_OCCUPANCY) > 0
         _assert_bitwise(legacy, hashed)
 
@@ -115,8 +121,7 @@ class TestHashParityMatrix:
         hashed = _stream(pid, pk, value, mesh=mesh,
                          has_group_clip=has_group_clip,
                          compact_merge=compact, segment_sort="hash")
-        assert profiler.event_count(columnar.EVENT_HASH_PASSES) > 0
-        assert profiler.event_count(columnar.EVENT_SORT_BYTES) == 0
+        assert _sampler_chunks() == {"hash": 8}
         _assert_bitwise(legacy, hashed)
 
     def test_hash_matches_tiled_and_packed(self):
@@ -131,7 +136,7 @@ class TestHashParityMatrix:
         pid, pk, value = _rle_data(seed=4)
         profiler.reset_events("ops/")
         auto = _stream(pid, pk, value, segment_sort="auto")
-        assert profiler.event_count(columnar.EVENT_HASH_PASSES) == 8
+        assert _sampler_chunks() == {"hash": 8}
         forced = _stream(pid, pk, value, segment_sort="hash")
         _assert_bitwise(auto, forced)
 
@@ -142,12 +147,12 @@ class TestHashParityMatrix:
         profiler.reset_events("ops/")
         _stream(pid, pk, value, segment_sort="auto",
                 need_flags=(True, True, True, True))
-        assert profiler.event_count(columnar.EVENT_HASH_PASSES) == 0
+        assert "hash" not in _sampler_chunks()
         # Continuous values defeat the integer grid: no gate, no hash.
         pid, pk, value = _rle_data(seed=6, integer_values=False)
         profiler.reset_events("ops/")
         _stream(pid, pk, value, segment_sort="auto")
-        assert profiler.event_count(columnar.EVENT_HASH_PASSES) == 0
+        assert "hash" not in _sampler_chunks()
 
     def test_continuous_values_forced_hash_ulp_contract(self):
         # Forced outside the gate: counts/pid-counts exact, sums
@@ -158,7 +163,7 @@ class TestHashParityMatrix:
         profiler.reset_events("ops/")
         hashed = _stream(pid, pk, value, has_group_clip=False,
                          segment_sort="hash")
-        assert profiler.event_count(columnar.EVENT_HASH_PASSES) == 8
+        assert _sampler_chunks() == {"hash": 8}
         np.testing.assert_array_equal(np.asarray(legacy.count),
                                       np.asarray(hashed.count))
         np.testing.assert_array_equal(np.asarray(legacy.pid_count),
@@ -198,7 +203,7 @@ class TestHashAdversarial:
         legacy = _stream(pid, pk, value, segment_sort=False)
         profiler.reset_events("ops/")
         hashed = _stream(pid, pk, value, segment_sort="hash")
-        assert profiler.event_count(columnar.EVENT_HASH_PASSES) == 0
+        assert _sampler_chunks() == {"general": 8}
         _assert_bitwise(legacy, hashed)
 
     def test_adversarial_group_hash_collisions(self, monkeypatch):
@@ -256,7 +261,7 @@ class TestHashAdversarial:
         hashed = _stream(pid, pk, value, n_parts=64, segment_sort="hash",
                          n_chunks=k)
         assert profiler.event_count(columnar.EVENT_HASH_DEMOTIONS) == 1
-        assert profiler.event_count(columnar.EVENT_HASH_PASSES) == 1
+        assert _sampler_chunks()["hash"] == 1
         _assert_bitwise(legacy, hashed)
 
     def test_overflow_demotion_mesh8(self, mesh):
@@ -460,14 +465,6 @@ class TestHashGateAndPlanning:
         f = wirecodec.plan_group_binning(crowded, "hash", 64)
         assert 0 < f.hash_bins < crowded.ucap
 
-    def test_sort_cost_hash_kind_zero_bytes(self):
-        c = columnar.sort_cost(100_000, num_partitions=1 << 10,
-                               pid_sorted=True, max_segments=4096,
-                               hash_bins=4096, hash_bin_rows=32)
-        assert c["kind"] == "hash"
-        assert c["operand_bytes"] == 0
-        assert c["rows"] == 4096 * 32 and c["tiles"] == 4096
-
     def test_resolved_sampler_desc(self):
         fmt = self._fmt()
         kw = dict(num_partitions=1 << 10, row_clip_lo=0.0, row_clip_hi=5.0,
@@ -487,6 +484,42 @@ class TestHashGateAndPlanning:
             fmt, "auto", 16, **{**kw,
                                 "need_flags": (True, True, True, True)})
         assert not norm.startswith("hash:")
+
+    def test_resolved_sampler_desc_strings(self):
+        # The bound-cache key of each sampler kind, pinned literally: a
+        # change here invalidates every cached accumulator.
+        kw = dict(num_partitions=1 << 10, row_clip_lo=0.0, row_clip_hi=5.0,
+                  linf_cap=6, l1_mode=False, group_clip_lo=-np.inf,
+                  group_clip_hi=np.inf,
+                  need_flags=(True, True, False, False))
+        head = ("WireFormat(bytes_pid=3, bits_pk=10, cap=32768, ucap=4096, "
+                "value=ValuePlan(mode=1, bits=0.0, lo=1.0, scale=3), ")
+        tiles = "tile_rows=1024, tile_slack=16, "
+        fmt = self._fmt()
+        cases = {
+            "hash": (fmt, "hash", kw,
+                     "pid_mode=0, bits_pid=0, " + tiles + "hash_bins=4096, "
+                     "hash_bin_rows=16, sort_value_narrow=True)"),
+            "tiled": (fmt, True, kw,
+                      "pid_mode=0, bits_pid=0, " + tiles + "hash_bins=0, "
+                      "hash_bin_rows=0, sort_value_narrow=True)"),
+            "packed": (fmt, False, kw,
+                       "pid_mode=0, bits_pid=0, tile_rows=0, tile_slack=0, "
+                       "hash_bins=0, hash_bin_rows=0, "
+                       "sort_value_narrow=False)"),
+            "general": (self._fmt(pid_mode=wirecodec.PID_PLANES), True, kw,
+                        "pid_mode=1, bits_pid=0, tile_rows=0, "
+                        "tile_slack=0, hash_bins=0, hash_bin_rows=0, "
+                        "sort_value_narrow=True)"),
+        }
+        for kind, (f, knob, args, tail) in cases.items():
+            assert (streaming.resolved_sampler_desc(f, knob, 16, **args)
+                    == f"{kind}:{head}{tail}"), kind
+        # L1 mode keeps a pid-sorted wire on the general sampler.
+        assert streaming.resolved_sampler_desc(
+            fmt, True, 16, **{**kw, "l1_mode": True}) == (
+                f"general:{head}pid_mode=0, bits_pid=0, {tiles}hash_bins=0, "
+                "hash_bin_rows=0, sort_value_narrow=True)")
 
 
 class TestSamplerFingerprints:

@@ -16,6 +16,7 @@ gate, and the VECTOR_SUM packed-sort plumbing.
 """
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -34,9 +35,17 @@ def mesh():
 
 
 @pytest.fixture(autouse=True)
-def _reset_sort_counters():
-    profiler.reset_events("ops/sort")
+def _reset_sampler_counters():
+    profiler.reset_events("ops/sampler_chunks/")
     yield
+
+
+def _sampler_chunks() -> dict:
+    """Executed chunk launches per sampler kind since the last reset
+    (kinds that ran none are left out)."""
+    counts = {kind: profiler.event_count(columnar.sampler_event(kind))
+              for kind in ("hash", "tiled", "packed", "general")}
+    return {kind: n for kind, n in counts.items() if n}
 
 
 def _rle_data(n=60_000, n_parts=300, seed=0, integer_values=True):
@@ -64,7 +73,7 @@ def _planes_data(n=60_000, n_parts=300, seed=1):
 
 
 def _stream(pid, pk, value, *, mesh=None, n_parts=300, has_group_clip=True,
-            **kw):
+            n_chunks=8, **kw):
     clips = (dict(row_clip_lo=-np.inf, row_clip_hi=np.inf, middle=0.0,
                   group_clip_lo=-30.0, group_clip_hi=30.0)
              if has_group_clip else
@@ -72,7 +81,8 @@ def _stream(pid, pk, value, *, mesh=None, n_parts=300, has_group_clip=True,
                   group_clip_lo=-np.inf, group_clip_hi=np.inf))
     args = (jax.random.PRNGKey(7), pid, pk, value)
     common = dict(num_partitions=n_parts, linf_cap=6, l0_cap=8,
-                  has_group_clip=has_group_clip, n_chunks=8, **clips, **kw)
+                  has_group_clip=has_group_clip, n_chunks=n_chunks, **clips,
+                  **kw)
     if mesh is not None:
         accs = sharded.stream_bound_and_aggregate(mesh, *args, **common)
     else:
@@ -95,25 +105,29 @@ class TestTiledSortParityMatrix:
         pid, pk, value = _rle_data()
         legacy = _stream(pid, pk, value, has_group_clip=has_group_clip,
                          compact_merge=compact, segment_sort=False)
-        profiler.reset_events("ops/sort")
+        profiler.reset_events("ops/sampler_chunks/")
         tiled = _stream(pid, pk, value, has_group_clip=has_group_clip,
                         compact_merge=compact, segment_sort=True)
-        # Non-vacuous: the tiled sampler actually ran.
-        assert profiler.event_count(columnar.EVENT_SORT_TILES) > 8
+        # Non-vacuous: every chunk ran the tiled sampler.
+        assert _sampler_chunks() == {"tiled": 8}
         _assert_bitwise(legacy, tiled)
 
     @pytest.mark.parametrize("has_group_clip", [True, False])
     @pytest.mark.parametrize("compact", [True, False])
     def test_rle_mesh8(self, mesh, has_group_clip, compact):
+        # Two chunks of 8 buckets: each bucket (~2,500 rows) is wider
+        # than one tile, so the tiled sampler can engage on the mesh.
         pid, pk, value = _rle_data(n=40_000)
         legacy = _stream(pid, pk, value, mesh=mesh,
                          has_group_clip=has_group_clip,
-                         compact_merge=compact, segment_sort=False)
-        profiler.reset_events("ops/sort")
+                         compact_merge=compact, segment_sort=False,
+                         n_chunks=2)
+        profiler.reset_events("ops/sampler_chunks/")
         tiled = _stream(pid, pk, value, mesh=mesh,
                         has_group_clip=has_group_clip,
-                        compact_merge=compact, segment_sort=True)
-        assert profiler.event_count(columnar.EVENT_SORT_TILES) > 8
+                        compact_merge=compact, segment_sort=True,
+                        n_chunks=2)
+        assert _sampler_chunks() == {"tiled": 2}
         _assert_bitwise(legacy, tiled)
 
     @pytest.mark.parametrize("has_group_clip", [True, False])
@@ -121,12 +135,12 @@ class TestTiledSortParityMatrix:
         pid, pk, value = _planes_data()
         legacy = _stream(pid, pk, value, has_group_clip=has_group_clip,
                          segment_sort=False)
-        profiler.reset_events("ops/sort")
+        profiler.reset_events("ops/sampler_chunks/")
         tiled = _stream(pid, pk, value, has_group_clip=has_group_clip,
                         segment_sort=True)
         # PID_PLANES rows arrive unsorted: tiling cannot engage — every
-        # executed chunk (n_chunks=8) reports exactly one global sort.
-        assert profiler.event_count(columnar.EVENT_SORT_TILES) == 8
+        # executed chunk (n_chunks=8) runs the general sort.
+        assert _sampler_chunks() == {"general": 8}
         _assert_bitwise(legacy, tiled)
 
     def test_planes_mesh8(self, mesh):
@@ -149,7 +163,7 @@ class TestTiledSortParityMatrix:
         # bucket), "auto" and True are the same kernel.
         pid, pk, value = _rle_data(n=300_000, seed=3)
         auto = _stream(pid, pk, value, segment_sort="auto")
-        assert profiler.event_count(columnar.EVENT_SORT_TILES) > 0
+        assert _sampler_chunks() == {"tiled": 8}
         forced = _stream(pid, pk, value, segment_sort=True)
         _assert_bitwise(auto, forced)
 
@@ -210,10 +224,10 @@ class TestTiledResumeParity:
 
 
 class TestSortByteCounters:
-    """The CI perf-counter smoke: at the 2^17-partition shape the tiled
-    run must report strictly fewer modeled sort operand bytes."""
+    """At the 2^17-partition shape the tiled sampler engages on every
+    chunk and releases the same bits as the global sort."""
 
-    def test_tiled_strictly_cheaper_at_128k_partitions(self):
+    def test_tiled_engages_at_128k_partitions(self):
         n_parts = 1 << 17
         rng = np.random.default_rng(5)
         n = 120_000
@@ -222,30 +236,12 @@ class TestSortByteCounters:
         value = rng.integers(0, 6, n).astype(np.float32)
         legacy = _stream(pid, pk, value, n_parts=n_parts,
                          segment_sort=False)
-        legacy_bytes = profiler.event_count(columnar.EVENT_SORT_BYTES)
-        legacy_rows = profiler.event_count(columnar.EVENT_SORT_ROWS)
-        assert legacy_bytes > 0 and legacy_rows > 0
-        profiler.reset_events("ops/sort")
+        assert "tiled" not in _sampler_chunks()
+        profiler.reset_events("ops/sampler_chunks/")
         tiled = _stream(pid, pk, value, n_parts=n_parts,
                         segment_sort=True)
-        tiled_bytes = profiler.event_count(columnar.EVENT_SORT_BYTES)
-        assert profiler.event_count(columnar.EVENT_SORT_TILES) > 8
-        assert tiled_bytes < legacy_bytes
+        assert _sampler_chunks() == {"tiled": 8}
         _assert_bitwise(legacy, tiled)
-
-    def test_sort_cost_model_shapes(self):
-        g = columnar.sort_cost(100_000, num_partitions=1 << 17)
-        p = columnar.sort_cost(100_000, num_partitions=1 << 17,
-                               pid_sorted=True, max_segments=4096)
-        t = columnar.sort_cost(100_000, num_partitions=1 << 17,
-                               pid_sorted=True, max_segments=4096,
-                               tile_rows=1024, tile_slack=64,
-                               value_bytes=1)
-        assert g["kind"] == "general" and g["tiles"] == 1
-        assert p["kind"] == "packed" and p["bytes_per_row"] < \
-            g["bytes_per_row"]
-        assert t["kind"] == "tiled" and t["tiles"] == -(-100_000 // 1024)
-        assert t["operand_bytes"] < p["operand_bytes"] < g["operand_bytes"]
 
 
 class TestPresortedFitsBoundary:
@@ -551,3 +547,108 @@ class TestQuantileTiledReplay:
         for name in legacy:
             np.testing.assert_array_equal(legacy[name], tiled[name],
                                           err_msg=name)
+
+
+# (n, statics, kind): one case per branch of columnar.sampler_kind, at a
+# row capacity small enough to trace every sampler.
+_PLAIN = dict(num_partitions=1 << 10, max_segments=None, pid_sorted=False,
+              l1_mode=False, tile_rows=0, tile_slack=0, hash_bins=0,
+              hash_bin_rows=0)
+_SORTED = dict(_PLAIN, pid_sorted=True, max_segments=32)
+_SAMPLER_CASES = {
+    "empty": (0, _SORTED, "empty"),
+    "general_unsorted": (64, _PLAIN, "general"),
+    "general_l1": (64, dict(_SORTED, l1_mode=True, tile_rows=16,
+                            tile_slack=8), "general"),
+    "general_key_does_not_fit": (
+        64, dict(_SORTED, num_partitions=1 << 30, max_segments=1 << 30),
+        "general"),
+    "packed": (64, _SORTED, "packed"),
+    "packed_tile_too_wide": (64, dict(_SORTED, tile_rows=48,
+                                      tile_slack=16), "packed"),
+    "tiled": (64, dict(_SORTED, tile_rows=16, tile_slack=8), "tiled"),
+    "hash": (64, dict(_SORTED, hash_bins=32, hash_bin_rows=8), "hash"),
+    "hash_over_tiles": (64, dict(_SORTED, tile_rows=16, tile_slack=8,
+                                 hash_bins=32, hash_bin_rows=8), "hash"),
+}
+_SAMPLERS = {
+    "hash": "_bin_rows_and_groups_hash",
+    "tiled": "_sample_rows_and_groups_tiled",
+    "packed": "_sample_rows_and_groups_presorted",
+    "general": "_sample_rows_and_groups",
+}
+
+
+class TestSamplerKind:
+    """columnar.sampler_kind is the one sampler decision: its table, and
+    _dispatch_sampler tracing exactly the sampler it names."""
+
+    @pytest.mark.parametrize("case", sorted(_SAMPLER_CASES))
+    def test_sampler_kind(self, case):
+        n, statics, kind = _SAMPLER_CASES[case]
+        assert columnar.sampler_kind(n, **statics) == kind
+
+    @pytest.mark.parametrize(
+        "case", sorted(c for c in _SAMPLER_CASES if c != "empty"))
+    def test_dispatch_follows_sampler_kind(self, case, monkeypatch):
+        n, statics, kind = _SAMPLER_CASES[case]
+        ran = []
+        for name, attr in _SAMPLERS.items():
+            def spy(*args, _name=name, _real=getattr(columnar, attr),
+                    **kwargs):
+                ran.append(_name)
+                return _real(*args, **kwargs)
+            monkeypatch.setattr(columnar, attr, spy)
+        pid = jnp.repeat(jnp.arange(n // 4, dtype=jnp.int32), 4)
+        pk = jnp.arange(n, dtype=jnp.int32) % 7
+        valid = jnp.ones((n,), dtype=bool)
+        l1_cap = jnp.int32(4) if statics["l1_mode"] else None
+        kw = {k: v for k, v in statics.items() if k != "l1_mode"}
+
+        def trace(key, value):
+            return columnar._dispatch_sampler(
+                key, pid, pk, valid, jnp.int32(2), jnp.int32(3), l1_cap,
+                value=value, **kw)
+
+        jax.make_jaxpr(trace)(jax.random.PRNGKey(0),
+                              jnp.ones((n,), jnp.float32))
+        assert ran == [kind]
+
+    @pytest.mark.parametrize("segment_sort", [False, True, "hash"])
+    def test_wire_chunk_kernel_runs_wire_sampler_kind(self, segment_sort,
+                                                      monkeypatch):
+        # The host's kind (counters, bound-cache key) is the kind the
+        # chunk program traces: n is the wire's row capacity fmt.cap.
+        pid, pk, value = _rle_data(n=20_000)
+        wire = streaming.ingest_resident_wire(pid, pk, value,
+                                              num_partitions=300,
+                                              n_chunks=2)
+        fmt, int_clip = streaming.finish_wire_plan(
+            wire.fmt, segment_sort, wire.max_run, num_partitions=300,
+            row_clip_lo=0.0, row_clip_hi=5.0, linf_cap=6, l1_mode=False,
+            need_flags=(True, True, False, False))
+        kind = streaming.wire_sampler_kind(fmt, num_partitions=300,
+                                           l1_mode=False)
+        assert kind == {False: "packed", True: "tiled",
+                        "hash": "hash"}[segment_sort]
+        ran = []
+        for name, attr in _SAMPLERS.items():
+            def spy(*args, _name=name, _real=getattr(columnar, attr),
+                    **kwargs):
+                ran.append(_name)
+                return _real(*args, **kwargs)
+            monkeypatch.setattr(columnar, attr, spy)
+        zeros = jnp.zeros((300,), jnp.float32)
+        accs = columnar.PartitionAccumulators(*([zeros] * 5))
+
+        def step(key, row):
+            return streaming._chunk_step_rle.__wrapped__(
+                key, row, int(wire.counts[0]), int(wire.n_uniq[0]), accs,
+                6, 8, 0.0, 5.0, 2.5, -np.inf, np.inf, None, int_clip,
+                num_partitions=300, fmt=fmt,
+                need_flags=(True, True, False, False),
+                int_accumulate=int_clip is not None)
+
+        jax.make_jaxpr(step)(jax.random.PRNGKey(0),
+                             jnp.asarray(wire.slab[0]))
+        assert ran == [kind]

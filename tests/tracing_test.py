@@ -340,6 +340,9 @@ class TestNoiseCounters:
         # the seeded fallback.
         for hook in ("sample_laplace", "sample_gaussian", "sample_uniform"):
             monkeypatch.setattr(noise_core, hook, getattr(noise_core, hook))
+        # A seed another test left set would route the selection uniforms
+        # through a numpy generator, which counts no draws.
+        monkeypatch.setattr(pdp.partition_selection, "_seeded_rng", None)
         if not loader.install():
             pytest.skip("native library unavailable (no compiler)")
         if len(os.sched_getaffinity(0)) < 2:
