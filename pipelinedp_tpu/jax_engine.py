@@ -395,16 +395,18 @@ class JaxDPEngine:
         # the knob into a 4-way general/packed/tiled/hash dispatch):
         #   "auto"  — hash-binned SORTLESS group stage when it is
         #             provably bit-identical (columnar.hash_exact_gate +
-        #             no norm columns), else the bucketed segment-local
-        #             tiled sort when the tile heuristic wins, else the
-        #             packed global sort. Bit-identical released values
-        #             across all of these by construction.
+        #             no norm columns), else the packed global sort.
+        #             Never the tiled sort: on a TPU its grid and
+        #             compaction gathers cost more than the shorter sort
+        #             saves. Bit-identical released values across all of
+        #             these by construction.
         #   "hash"  — force the sortless group stage whenever its grid
         #             geometry is computable (chunks that overflow the
         #             bins demote to tiled per chunk): zero sort passes;
         #             exact counts always, sums ULP-close outside the
         #             exactness gate (bit-identical inside it).
-        #   True    — force tiling whenever geometry permits.
+        #   True    — force the bucketed segment-local tiled sort
+        #             whenever geometry permits.
         #   False   — the full round-8 kernel (global packed sort, f32
         #             payload, float accumulation — the parity oracle).
         self._segment_sort = segment_sort

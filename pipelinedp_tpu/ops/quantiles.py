@@ -28,7 +28,7 @@ divergence in sort configuration between the two kernels would silently
 de-correlate "rows kept for COUNT/SUM" from "rows kept for PERCENTILE" of
 one release. The tiled and global packed sorts are bit-identical by
 construction (ops/columnar._sample_rows_and_groups_tiled), which is what
-lets segment_sort="auto" flip geometry per chunk without touching the
+lets the segment_sort knob pick either geometry without touching the
 replayed masks.
 """
 

@@ -112,10 +112,11 @@ def finish_wire_plan(fmt, segment_sort, max_run, *, num_partitions: int,
     (parallel/sharded.py) so both paths resolve the segment_sort knob
     and the int32-accumulation gate identically.
 
-    fmt gains tile geometry and (segment_sort "hash", or "auto" under
-    the order-exactness gate) the hash-bin grid of the sortless group
-    stage (wirecodec.plan_group_binning — the 4-way
-    general/packed/tiled/hash dispatch); int_clip is the int32 row-clip
+    fmt gains (segment_sort "hash", or "auto" under the order-exactness
+    gate) the hash-bin grid of the sortless group stage and (True, or
+    "hash" as its demotion target) tile geometry
+    (wirecodec.plan_group_binning — the 4-way general/packed/tiled/hash
+    dispatch); int_clip is the int32 row-clip
     pair when VALUE_PLANES chunks may accumulate in int32 bit-identically
     (columnar.int_accumulation_plan), else None.
 
@@ -124,9 +125,12 @@ def finish_wire_plan(fmt, segment_sort, max_run, *, num_partitions: int,
     (every float32 partial sum is an exact integer, so the different
     accumulation order cannot change a bit), the kernel reads no norm
     columns (mean/variance sums are non-integer), no L1 mode, and the
-    grid fits every chunk. segment_sort="hash" forces the stage whenever
-    its geometry is computable — exact counts, ULP-close sums outside
-    the gate, with the tiled path as the parity oracle.
+    grid fits every chunk. Otherwise "auto" runs the packed global sort
+    and never tiles: on a TPU the tiled sampler's gathers cost more than
+    the shorter sort saves (wirecodec.plan_segment_tiling).
+    segment_sort="hash" forces the stage whenever its geometry is
+    computable — exact counts, ULP-close sums outside the gate, with the
+    tiled path as the parity oracle.
 
     segment_sort=False is the full round-8 parity oracle: no tiling, the
     value widens to float32 at decode (f32 sort payload), and the group
@@ -709,14 +713,17 @@ def stream_bound_and_aggregate(
       With group-level sum clipping active the released accumulators are
       bit-identical to the legacy path; without it they agree in exact
       arithmetic (float32 association may differ in the last ulp).
-    segment_sort: the bucketed segment-local sort inside the chunk kernel
-      (columnar tiled sampler; wirecodec.plan_segment_tiling), plus the
-      narrow-dtype sort payload and int32 group accumulation that ride
-      with it. "auto" (default) engages on the pid-sorted wire when the
-      tile heuristic wins; True forces tiling whenever geometry permits;
-      False restores the full round-8 kernel (global packed sort, f32
-      payload, float accumulation — the parity oracle). BIT-identical
-      released values in every mode — the knob is pure kernel geometry.
+    segment_sort: the group stage of the chunk kernel
+      (wirecodec.plan_group_binning), plus the narrow-dtype sort payload
+      and int32 group accumulation that ride with it. "auto" (default)
+      runs the sortless hash stage under its exactness gate, else the
+      packed global sort — never tiles, whose gathers cost more on a TPU
+      than the shorter sort saves; True forces the bucketed
+      segment-local tiled sort whenever geometry permits; "hash" forces
+      the hash stage; False restores the full round-8 kernel (global
+      packed sort, f32 payload, float accumulation — the parity oracle).
+      BIT-identical released values in every mode but forced "hash"
+      outside its gate — the knob is pure kernel geometry.
 
     Returns per-partition accumulators on device, identical in distribution
     to the single-shot kernel.

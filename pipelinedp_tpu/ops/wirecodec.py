@@ -751,17 +751,25 @@ def plan_segment_tiling(fmt: WireFormat, segment_sort,
                         max_run: int) -> WireFormat:
     """Resolves the ``segment_sort`` knob into tile geometry on ``fmt``.
 
-    segment_sort: False disables; True forces tiling whenever the
-    geometry is non-degenerate; "auto" additionally requires enough tiles
-    per bucket (>= 8) that the shorter sort span pays for the binning and
-    compaction passes. Tiling needs the max single-pid run (``max_run``,
-    from prep-time per-pid counts — tile_slack must bound every segment;
-    unknown/-1 disables) and pid-sorted arrival (PID_RLE).
+    segment_sort: True (and "hash", whose overflowing chunks demote to
+    tiles) tiles whenever the geometry is non-degenerate; False and
+    "auto" never tile, so a pid-sorted chunk the hash stage does not take
+    runs the packed global sort. The tiled sampler saves sort depth but
+    pays for it in index-driven gathers (the grid binning, the tile
+    compaction and a searchsorted over every row); on a TPU the gathers
+    cost far more than the sort levels they save (TPU v5e, 16 chunks of
+    12.7M rows: 30.0 s of gathers around a 0.71 s tile sort, against
+    0.84 s for the packed sort of the whole chunks).
+    Both sorts are bit-identical, so "auto" changes no released value.
+    Tiling needs the max single-pid run (``max_run``, from prep-time
+    per-pid counts — tile_slack must bound every segment; unknown/-1
+    disables) and pid-sorted arrival (PID_RLE).
 
     Tile width: the smallest power of two >= 4 * max_run (so slack stays
     <= ~25% of a tile) and >= 1024 (smaller tiles are all padding).
     """
-    if segment_sort is False or fmt.pid_mode != PID_RLE:
+    if (segment_sort is False or segment_sort == "auto"
+            or fmt.pid_mode != PID_RLE):
         return fmt
     if max_run is None or max_run <= 0:
         return fmt
@@ -769,14 +777,12 @@ def plan_segment_tiling(fmt: WireFormat, segment_sort,
     tile = 1 << max(10, (4 * max_run - 1).bit_length())
     if tile + slack >= fmt.cap:
         return fmt
-    if segment_sort == "auto" and tile > fmt.cap // 8:
-        return fmt
     return dataclasses.replace(fmt, tile_rows=tile, tile_slack=slack)
 
 
 # Hash-binned group-stage geometry limits (plan_group_binning). The bin
 # width bounds the O(W^2) pairwise selection per segment — beyond
-# HASH_MAX_BIN_ROWS the quadratic term loses to the tiled sort, so auto
+# HASH_MAX_BIN_ROWS the quadratic term loses to the sort, so auto
 # declines (forced "hash" tolerates up to HASH_FORCED_MAX_BIN_ROWS, the
 # compile-sanity ceiling). HASH_GRID_BLOWUP bounds the [bins, width]
 # grid relative to the chunk's rows: bins beyond the budget are not
@@ -797,9 +803,10 @@ def plan_group_binning(fmt: WireFormat, segment_sort, max_run: int, *,
     within the forced ceiling); "auto" additionally requires ``exact``
     (the caller-evaluated columnar.hash_exact_gate — bit-identity to
     the sorted paths), the auto bin-width ceiling, and bins for every
-    chunk within the grid budget (so auto never mixes kernels). The
-    tile geometry is always resolved too: it is the per-chunk demotion
-    target when a chunk's RLE entry count exceeds hash_bins.
+    chunk within the grid budget (so auto never mixes kernels). Under
+    "hash" the tile geometry is resolved too: it is the per-chunk
+    demotion target when a chunk's RLE entry count exceeds hash_bins
+    ("auto" plans no tiles, and never demotes).
 
     Bin sizing from the row_packer prep stats: width = the max
     single-pid run rounded up (a segment can never overflow its bin —

@@ -759,12 +759,12 @@ def stream_bound_and_aggregate(mesh: Mesh,
     padded partitions; False restores the legacy per-chunk
     scatter+reduce-scatter loop.
 
-    segment_sort: the bucketed segment-local sort inside each device's
-    chunk kernel, as on the single-device path (streaming
-    .stream_bound_and_aggregate) — "auto"/True/False resolve through the
-    shared streaming.finish_wire_plan, so mesh and single-device runs of
-    the same wire make the same tiling decision. BIT-identical released
-    values either way.
+    segment_sort: the group stage of each device's chunk kernel, as on
+    the single-device path (streaming.stream_bound_and_aggregate) —
+    every setting resolves through the shared streaming.finish_wire_plan,
+    so mesh and single-device runs of the same wire pick the same
+    sampler ("auto": hash under its exactness gate, else the packed
+    sort; True tiles). BIT-identical released values either way.
     """
     import dataclasses
 

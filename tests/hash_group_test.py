@@ -475,10 +475,14 @@ class TestHashGateAndPlanning:
         forced = streaming.resolved_sampler_desc(fmt, "hash", 16, **kw)
         legacy = streaming.resolved_sampler_desc(fmt, False, 16, **kw)
         tiled = streaming.resolved_sampler_desc(fmt, True, 16, **kw)
-        # Same resolved kernel -> same identity; different kernels ->
-        # different identities (the satellite-1 contract).
-        assert auto == forced and auto.startswith("hash:")
-        assert legacy != forced and tiled != forced
+        # Same resolved kernel and geometry -> same identity; otherwise
+        # different identities (the satellite-1 contract). auto plans no
+        # tiles, so it differs from forced hash only by forced's tiled
+        # demotion target.
+        assert auto.startswith("hash:")
+        assert auto == forced.replace("tile_rows=1024, tile_slack=16",
+                                      "tile_rows=0, tile_slack=0")
+        assert legacy != forced and tiled != forced and auto != forced
         # auto outside the gate (norm columns) resolves to a sorted kind.
         norm = streaming.resolved_sampler_desc(
             fmt, "auto", 16, **{**kw,
@@ -560,11 +564,14 @@ class TestSamplerFingerprints:
             h0 = profiler.event_count(session_mod.EVENT_BOUND_HITS)
             m0 = profiler.event_count(session_mod.EVENT_BOUND_MISSES)
             a = self._engine_query(session, "auto")
-            # Same seed, different knob STRING, same resolved sampler
-            # (auto resolves to hash for COUNT+SUM on this wire): HIT.
-            b = self._engine_query(session, "hash")
+            # Same seed, same resolved sampler (auto resolves to hash for
+            # COUNT+SUM on this wire): HIT.
+            self._engine_query(session, "auto")
             assert profiler.event_count(
                 session_mod.EVENT_BOUND_HITS) == h0 + 1
+            # Forced hash carries a tiled demotion target that auto does
+            # not plan: another geometry, so a MISS — with the same bits.
+            b = self._engine_query(session, "hash")
             for name in a:
                 np.testing.assert_array_equal(a[name], b[name],
                                               err_msg=name)
@@ -574,7 +581,9 @@ class TestSamplerFingerprints:
             # gate.
             self._engine_query(session, False)
             assert profiler.event_count(
-                session_mod.EVENT_BOUND_MISSES) == m0 + 2
+                session_mod.EVENT_BOUND_HITS) == h0 + 1
+            assert profiler.event_count(
+                session_mod.EVENT_BOUND_MISSES) == m0 + 3
         finally:
             session.close()
 

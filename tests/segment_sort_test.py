@@ -4,8 +4,9 @@ The contract pinned here (ISSUE 7 tentpole): ``segment_sort`` is pure
 kernel geometry. Released accumulators, kept partitions, and replayed
 sampling are BIT-identical whether the packed 3-key bounding sort runs
 globally over the whole chunk (legacy, ``segment_sort=False``) or over
-fixed-width bucket tiles with the narrow value payload and int32 group
-accumulation (``segment_sort=True``/``"auto"``), across:
+fixed-width bucket tiles (``segment_sort=True``), with the narrow value
+payload and int32 group accumulation that ``"auto"``'s packed sort also
+carries, across:
 
   {RLE, PID_PLANES} x {group-clip, no-clip} x {single-device, mesh8}
   x {compact merge on/off},
@@ -159,13 +160,18 @@ class TestTiledSortParityMatrix:
         _assert_bitwise(legacy, tiled)
 
     def test_auto_matches_forced_when_engaged(self):
-        # At a shape where the auto heuristic engages (>= 8 tiles per
-        # bucket), "auto" and True are the same kernel.
+        # At a shape where forced tiling engages, "auto" still runs the
+        # packed global sort on every chunk, and releases the same bits
+        # as forced tiling and the round-8 oracle.
         pid, pk, value = _rle_data(n=300_000, seed=3)
         auto = _stream(pid, pk, value, segment_sort="auto")
-        assert _sampler_chunks() == {"tiled": 8}
+        assert _sampler_chunks() == {"packed": 8}
+        profiler.reset_events("ops/sampler_chunks/")
         forced = _stream(pid, pk, value, segment_sort=True)
+        assert _sampler_chunks() == {"tiled": 8}
+        legacy = _stream(pid, pk, value, segment_sort=False)
         _assert_bitwise(auto, forced)
+        _assert_bitwise(auto, legacy)
 
 
 class TestTiledResumeParity:
@@ -288,7 +294,8 @@ class TestPlanSegmentTiling:
 
     def test_auto_requires_enough_tiles(self):
         fmt = self._fmt(cap=1 << 12)
-        # tile 1024 > cap/8: auto declines, True forces.
+        # auto never tiles; True forces a tile as long as one tile plus
+        # its slack leaves the bucket room.
         assert wirecodec.plan_segment_tiling(fmt, "auto", 16).tile_rows == 0
         forced = wirecodec.plan_segment_tiling(fmt, True, 16)
         assert forced.tile_rows == 1024 and forced.tile_slack == 16
@@ -306,10 +313,23 @@ class TestPlanSegmentTiling:
             self._fmt(cap=1 << 12), True, 1 << 11).tile_rows == 0
 
     def test_slack_bounds_max_run(self):
-        fmt = wirecodec.plan_segment_tiling(self._fmt(), "auto", 100)
+        fmt = wirecodec.plan_segment_tiling(self._fmt(), True, 100)
         assert fmt.tile_rows >= 4 * 100
         assert fmt.tile_slack >= 100
         assert fmt.tile_rows % 2 == 0 and fmt.tile_slack % 8 == 0
+
+    # (cap, max_run): small buckets, wide ones, and the Netflix Prize's
+    # chunk (12,728,480 rows per bucket, a heaviest user of 17,653 rows).
+    @pytest.mark.parametrize("cap,max_run", [
+        (1 << 15, 16), (1 << 15, 100), (1 << 20, 1000),
+        (12_728_480, 17_653), (1 << 24, 100)])
+    def test_auto_never_tiles(self, cap, max_run):
+        fmt = self._fmt(cap=cap)
+        auto = wirecodec.plan_segment_tiling(fmt, "auto", max_run)
+        assert (auto.tile_rows, auto.tile_slack) == (0, 0)
+        forced = wirecodec.plan_segment_tiling(fmt, True, max_run)
+        assert forced.tile_rows >= 4 * max_run
+        assert forced.tile_slack >= max_run
 
 
 class TestIntAccumulationPlan:

@@ -7,10 +7,10 @@ Contracts:
     without drawing the rows; a draw has one row per (user, movie) pair,
     half-star values, every movie, and rows in user order;
   * half stars ship as bit-planes at scale 0.5, exactly;
-  * each lane of ``query_batch`` (the untiled batched sampler) is
+  * each lane of ``query_batch`` (the batched sampler, wide payload) is
     bit-identical to the same config's ``session.query`` and to a cold
-    ``JaxDPEngine.aggregate`` (tiled: the heaviest user's run is too long
-    for hash bins);
+    ``JaxDPEngine.aggregate`` (the packed sort with the narrow payload:
+    the heaviest user's run is too long for hash bins);
   * the cell's run (generator, driver, reference, metrics) is correct,
     and comes out not correct with the control (no contribution
     bounding) or a planted fault in the program's place: lane 0's
@@ -151,8 +151,10 @@ def test_half_stars_ship_as_planes_at_half_scale(session, columns):
 
 def test_sequential_lanes_tile_and_the_batch_does_not(session, batch):
     # The heaviest user's run is too long for hash bins, so a config
-    # asked alone takes the tiled sampler; the batch forces the untiled
-    # one, and the lane comparisons below span the two.
+    # asked alone takes the packed sort with the narrow plane-index
+    # payload and int32 accumulation (auto never tiles); the batch
+    # forces the wide-payload oracle statics, and the lane comparisons
+    # below span the two.
     wire = session._wire
     assert wire.max_run == SMALL_DATA["user_rows"]["max"]
     assert wire.max_run > wirecodec.HASH_MAX_BIN_ROWS
@@ -160,7 +162,9 @@ def test_sequential_lanes_tile_and_the_batch_does_not(session, batch):
     for cfg in configs:
         p = session._prepare_query(0, cfg, False)
         desc = session._resolved_sampler(None, session._batch_kw(p))
-        assert desc.startswith("tiled:"), desc
+        assert desc.startswith("packed:"), desc
+        assert "tile_rows=0," in desc, desc
+        assert desc.endswith("sort_value_narrow=True)"), desc
 
 
 @pytest.mark.parametrize("lane", range(8))
